@@ -11,6 +11,7 @@ mesh inside a subprocess (device count must be set before jax init).
 from __future__ import annotations
 
 import json
+import os
 import subprocess
 import sys
 import time
@@ -24,8 +25,9 @@ import jax
 from repro import configs
 from repro.core import fetchsgd as F
 from repro.launch import analysis, shapes, steps
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 cfg = configs.get_smoke("gpt2s-federated")
 shape = shapes.ShapeSpec("t", "train", 128, 8)
 out = {}
@@ -43,8 +45,11 @@ print(json.dumps(out))
 
 def run() -> list[tuple[str, float, str]]:
     t0 = time.time()
+    # the child counts HLO bytes on a forced host mesh: keep it on the CPU,
+    # off the accelerator the parent process may already hold
     proc = subprocess.run([sys.executable, "-c", _SCRIPT],
-                          capture_output=True, text=True, timeout=1200)
+                          capture_output=True, text=True, timeout=1200,
+                          env={**os.environ, "JAX_PLATFORMS": "cpu"})
     us = (time.time() - t0) * 1e6
     if proc.returncode != 0:
         return [("sec32_sketch_aggregation", us,

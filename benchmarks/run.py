@@ -19,6 +19,8 @@ import inspect
 import sys
 import traceback
 
+from repro.xla_env import enable_compile_cache
+
 from . import (bench_aggregation_modes, bench_compression, bench_convergence,
                bench_kernels, bench_simscale, bench_simtime,
                bench_sketch_aggregation, bench_true_topk, trajectory)
@@ -51,6 +53,7 @@ def main(argv=None) -> None:
                          "sample their largest scales at smaller id counts "
                          "(annotated sampled_n=); others are unaffected")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     modules = MODULES
     if args.only is not None:

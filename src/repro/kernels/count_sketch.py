@@ -1,33 +1,37 @@
 """Pallas TPU kernels for Count Sketch encode / decode.
 
-TPU adaptation (see DESIGN.md §2): GPU count-sketch kernels rely on atomic
-scatter-add in HBM; the TPU has no atomics, and per-element dynamic stores
-defeat the VPU's 8x128 vector lanes.  We restructure both directions around
-the MXU:
+TPU adaptation: GPU count-sketch kernels rely on atomic scatter-add in
+HBM; the TPU has no atomics, and per-element dynamic stores defeat the
+VPU's 8x128 vector lanes.  We restructure both directions around the MXU:
 
+* **layout**: a chunk of ``n`` values reaches the kernel as a lane-dense
+  ``(rows_pad, 128)`` view, zero-padded to whole ``(BLOCK_ROWS, 128)``
+  tiles.  The block shape is then fixed by the TPU's (8, 128) tile and
+  not by XLA's tiling of the 1-D operand, which changes with ``n``.
 * **encode**: split the bucket index as ``idx = (outer, lane) =
-  (idx // 128, idx % 128)``.  For a block of ``B`` gradient elements build a
-  one-hot outer matrix ``O in {0,1}^(B x C_o)`` and a lane-masked value
-  matrix ``VL in R^(B x 128)`` whose row ``b`` is ``sign_b * v_b`` at column
-  ``lane_b``.  Then the block's contribution to sketch row ``j`` is the
+  (idx // 128, idx % 128)``.  For one 128-element row of the block build
+  the transposed one-hot outer matrix ``O^T in {0,1}^(C_o x 128)`` and the
+  transposed lane-masked value matrix ``VL^T in R^(128 x 128)``, whose
+  column ``e`` is ``sign_e * v_e`` at row ``lane_e``; both keep the
+  elements on lanes.  The row's contribution to sketch row ``j`` is the
   systolic matmul ``O^T @ VL in R^(C_o x 128)`` — a scatter expressed as
   dense contraction.  The (rows, C_o, 128) accumulator stays resident in
   VMEM across the grid (out-block index map is constant), so HBM sees each
-  gradient element exactly once: the kernel is read-bound at ``4 bytes /
-  (rows * C_o * 128 * 2) FLOPs`` per element — MXU-cheap for the sketch
-  sizes FetchSGD uses (c <= ~2**20).
-
+  gradient element exactly once.
 * **decode (estimate)**: the gather ``table[j, h_j(i)]`` becomes the same
-  one-hot contraction transposed, ``(O @ T_j) . Lane``, followed by a
-  median-of-rows on the VPU.
+  one-hot contraction the other way, ``T_j^T @ O^T`` masked by the lane
+  one-hot and summed over sublanes, followed by a sort-free median of the
+  rows (a min/max network: Mosaic has no sort).
 
-Hashes (murmur-finalizer over 64-bit ids carried as two uint32 words) are
-computed on the fly from ``iota`` — no index tables in HBM, matching
-``repro.core.hashing`` bit-for-bit so sketches from the kernel and the jnp
-path are interchangeable.
+Value contractions run at ``HIGHEST`` precision: at the default one the
+MXU rounds f32 operands to bf16.  Hashes (murmur-finalizer over 64-bit ids
+carried as two uint32 words) are computed on the fly from ``iota`` — no
+index tables in HBM, matching ``repro.core.hashing`` bit-for-bit so
+sketches from the kernel and the jnp path are interchangeable.
 
-Validated in ``interpret=True`` mode on CPU against ``ref.py``; compiled
-path targets TPU (MXU tile sizes: B multiple of 8, lanes fixed at 128).
+Validated in ``interpret=True`` mode on CPU against ``ref.py``; the
+compiled kernels are compiled for a described v5e in
+``tests/test_tpu_compile.py``.
 """
 
 from __future__ import annotations
@@ -37,126 +41,165 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import hashing
 
 LANES = 128
+BLOCK_ROWS = 8            # sublanes of one f32 tile: 1024 elements per step
 U32 = jnp.uint32
+HIGHEST = jax.lax.Precision.HIGHEST
+# Scalars (offset words, learning rate) live in SMEM, not in a vector tile.
+SMEM_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
-def _ids_for_block(offset_lo: jnp.ndarray, offset_hi: jnp.ndarray, start: jnp.ndarray,
-                   block: int):
-    """uint32 (hi, lo) id words for elements start..start+block of the chunk."""
-    i = jax.lax.broadcasted_iota(U32, (block,), 0) + start.astype(U32)
-    lo = offset_lo + i
-    carry = (lo < offset_lo).astype(U32)
+def lane_dense(x: jax.Array) -> jax.Array:
+    """Flatten ``x`` and zero-pad it into a ``(k * BLOCK_ROWS, 128)`` view."""
+    x = x.reshape(-1)
+    tile = BLOCK_ROWS * LANES
+    n_pad = (-x.shape[0]) % tile or (tile if x.shape[0] == 0 else 0)
+    if n_pad:
+        x = jnp.concatenate([x, jnp.zeros((n_pad,), x.dtype)])
+    return x.reshape(-1, LANES)
+
+
+def block_ids(off_lo, off_hi, start, shape):
+    """uint32 (hi, lo) id words of a ``(R, 128)`` block whose element
+    ``(r, l)`` is element ``start + r * 128 + l`` of the chunk."""
+    r = jax.lax.broadcasted_iota(U32, shape, 0)
+    lane = jax.lax.broadcasted_iota(U32, shape, 1)
+    i = start.astype(U32) + r * U32(LANES) + lane
+    lo = off_lo + i
     # NOTE: start fits in uint32 (chunks are capped at 2**28 elements), so a
     # single carry word is exact.
-    hi = offset_hi + carry
+    hi = off_hi + (lo < off_lo).astype(U32)
     return hi, lo
 
 
+def onehots_t(idx_row, c_outer: int):
+    """Transposed one-hots of one 128-element row of bucket indices:
+    ``(C_o, 128)`` outer and ``(128, 128)`` lane, elements on lanes."""
+    outer = idx_row // LANES
+    lane = idx_row % LANES
+    o_t = (jax.lax.broadcasted_iota(jnp.int32, (c_outer, LANES), 0)
+           == outer).astype(jnp.float32)
+    l_t = (jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 0)
+           == lane).astype(jnp.float32)
+    return o_t, l_t
+
+
+def scatter_tile(o_t, vl_t, precision=None):
+    """``O^T @ VL``: contract the element (lane) dim of both -> (C_o, 128)."""
+    return jax.lax.dot_general(o_t, vl_t, (((1,), (1,)), ((), ())),
+                               precision=precision,
+                               preferred_element_type=jnp.float32)
+
+
+def median_rows(xs: list) -> jax.Array:
+    """Elementwise median of a static list of arrays, without a sort.
+
+    An odd-even transposition network of min/max puts the values in
+    order; an even count averages the middle two like ``jnp.median``.
+    """
+    xs = list(xs)
+    n = len(xs)
+    for p in range(n):
+        for i in range(p % 2, n - 1, 2):
+            xs[i], xs[i + 1] = (jnp.minimum(xs[i], xs[i + 1]),
+                                jnp.maximum(xs[i], xs[i + 1]))
+    m = n // 2
+    if n % 2:
+        return xs[m]
+    return (xs[m - 1] + xs[m]) * 0.5
+
+
 def _encode_kernel(off_ref, values_ref, out_ref, *, rows: int, cols: int,
-                   key: int, block: int):
+                   key: int):
     pid = pl.program_id(0)
 
     @pl.when(pid == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    start = pid * block
-    hi, lo = _ids_for_block(off_ref[0], off_ref[1], start, block)
+    shape = (BLOCK_ROWS, LANES)
+    hi, lo = block_ids(off_ref[0], off_ref[1], pid * BLOCK_ROWS * LANES,
+                       shape)
     v = values_ref[...].astype(jnp.float32)
     c_outer = cols // LANES
-    outer_iota = jax.lax.broadcasted_iota(jnp.int32, (block, c_outer), 1)
-    lane_iota = jax.lax.broadcasted_iota(jnp.int32, (block, LANES), 1)
     for j in range(rows):
         idx = hashing.bucket_hash(lo, hi, j, cols, key)
-        sgn = hashing.sign_hash(lo, hi, j, key)
-        outer = (idx // LANES)[:, None]
-        lane = (idx % LANES)[:, None]
-        onehot_outer = (outer_iota == outer).astype(jnp.float32)      # (B, C_o)
-        vl = (lane_iota == lane).astype(jnp.float32) * (sgn * v)[:, None]  # (B, 128)
-        tile = jax.lax.dot_general(
-            onehot_outer, vl, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)                        # (C_o, 128)
-        out_ref[j, :, :] += tile
+        sv = hashing.sign_hash(lo, hi, j, key) * v
+        acc = None
+        for r in range(BLOCK_ROWS):
+            o_t, l_t = onehots_t(idx[r:r + 1, :], c_outer)
+            tile = scatter_tile(o_t, l_t * sv[r:r + 1, :], HIGHEST)
+            acc = tile if acc is None else acc + tile
+        out_ref[j, :, :] += acc
 
 
 def sketch_encode_words(values: jax.Array, off: jax.Array, rows: int,
-                        cols: int, key: int = 0, *, block: int = 512,
+                        cols: int, key: int = 0, *,
                         interpret: bool = False) -> jax.Array:
     """Pallas encode with a *traced* 64-bit base offset ``off = [lo, hi]``.
 
     Used by expert-parallel shards (the global offset of the local gradient
     slice depends on the on-device shard index) and by the scanned sketch
-    path.  ``cols % 128 == 0``; values zero-padded to a block multiple
-    (zero contributions are exact no-ops in the sketch).
+    path.  ``cols % 128 == 0``; values zero-padded to whole tiles (zero
+    contributions are exact no-ops in the sketch).
     """
     if cols % LANES != 0:
         raise ValueError(f"Pallas encode needs cols % {LANES} == 0, got {cols}")
-    values = values.reshape(-1)
-    n = values.shape[0]
-    n_pad = (-n) % block
-    if n_pad:
-        values = jnp.concatenate([values, jnp.zeros((n_pad,), values.dtype)])
-    num_blocks = values.shape[0] // block
+    v2 = lane_dense(values)
     c_outer = cols // LANES
-    off = off.astype(U32)
-
     out = pl.pallas_call(
-        functools.partial(_encode_kernel, rows=rows, cols=cols, key=key,
-                          block=block),
-        grid=(num_blocks,),
+        functools.partial(_encode_kernel, rows=rows, cols=cols, key=key),
+        grid=(v2.shape[0] // BLOCK_ROWS,),
         in_specs=[
-            pl.BlockSpec((2,), lambda i: (0,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
+            SMEM_SPEC,
+            pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((rows, c_outer, LANES), lambda i: (0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, c_outer, LANES), jnp.float32),
         interpret=interpret,
-    )(off, values)
+    )(off.astype(U32), v2)
     return out.reshape(rows, cols)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("offset", "rows", "cols", "key", "block",
+                   static_argnames=("offset", "rows", "cols", "key",
                                     "interpret"))
 def sketch_encode(values: jax.Array, offset: int, rows: int, cols: int,
-                  key: int = 0, *, block: int = 512,
-                  interpret: bool = False) -> jax.Array:
+                  key: int = 0, *, interpret: bool = False) -> jax.Array:
     """Pallas count-sketch encode of a 1-D chunk (static offset)."""
     off = jnp.array([offset & 0xFFFFFFFF, offset >> 32], dtype=U32)
-    return sketch_encode_words(values, off, rows, cols, key, block=block,
+    return sketch_encode_words(values, off, rows, cols, key,
                                interpret=interpret)
 
 
-def _estimate_kernel(off_ref, table_ref, out_ref, *, rows: int, cols: int,
-                     key: int, block: int):
+def _estimate_kernel(off_ref, table_t_ref, out_ref, *, rows: int, cols: int,
+                     key: int):
     pid = pl.program_id(0)
-    start = pid * block
-    hi, lo = _ids_for_block(off_ref[0], off_ref[1], start, block)
+    shape = (BLOCK_ROWS, LANES)
+    hi, lo = block_ids(off_ref[0], off_ref[1], pid * BLOCK_ROWS * LANES,
+                       shape)
     c_outer = cols // LANES
-    outer_iota = jax.lax.broadcasted_iota(jnp.int32, (block, c_outer), 1)
-    lane_iota = jax.lax.broadcasted_iota(jnp.int32, (block, LANES), 1)
-    ests = []
-    for j in range(rows):
-        idx = hashing.bucket_hash(lo, hi, j, cols, key)
-        sgn = hashing.sign_hash(lo, hi, j, key)
-        outer = (idx // LANES)[:, None]
-        lane = (idx % LANES)[:, None]
-        onehot_outer = (outer_iota == outer).astype(jnp.float32)   # (B, C_o)
-        t_j = table_ref[j, :, :]                                   # (C_o, 128)
-        picked = jax.lax.dot_general(
-            onehot_outer, t_j, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)                     # (B, 128)
-        lane_onehot = (lane_iota == lane).astype(jnp.float32)
-        ests.append(sgn * jnp.sum(picked * lane_onehot, axis=1))
-    out_ref[...] = jnp.median(jnp.stack(ests), axis=0)
+    idxs = [hashing.bucket_hash(lo, hi, j, cols, key) for j in range(rows)]
+    sgns = [hashing.sign_hash(lo, hi, j, key) for j in range(rows)]
+    for r in range(BLOCK_ROWS):
+        ests = []
+        for j in range(rows):
+            o_t, l_t = onehots_t(idxs[j][r:r + 1, :], c_outer)
+            cells = jax.lax.dot_general(
+                table_t_ref[j, :, :], o_t, (((1,), (0,)), ((), ())),
+                precision=HIGHEST,
+                preferred_element_type=jnp.float32)              # (128, 128)
+            ests.append(sgns[j][r:r + 1, :]
+                        * jnp.sum(cells * l_t, axis=0, keepdims=True))
+        out_ref[r:r + 1, :] = median_rows(ests)
 
 
 def sketch_estimate_words(table: jax.Array, off: jax.Array, n: int,
-                          key: int = 0, *, block: int = 512,
+                          key: int = 0, *,
                           interpret: bool = False) -> jax.Array:
     """Pallas decode with a *traced* 64-bit base offset ``off = [lo, hi]``.
 
@@ -167,27 +210,28 @@ def sketch_estimate_words(table: jax.Array, off: jax.Array, n: int,
     if cols % LANES != 0:
         raise ValueError(f"Pallas estimate needs cols % {LANES} == 0, got {cols}")
     c_outer = cols // LANES
-    n_blocks = -(-n // block)
+    n_blocks = max(1, -(-n // (BLOCK_ROWS * LANES)))
+    # (rows, 128, C_o): lanes of the table on sublanes, for an NN matmul
+    table_t = table.reshape(rows, c_outer, LANES).transpose(0, 2, 1)
     out = pl.pallas_call(
-        functools.partial(_estimate_kernel, rows=rows, cols=cols, key=key,
-                          block=block),
+        functools.partial(_estimate_kernel, rows=rows, cols=cols, key=key),
         grid=(n_blocks,),
         in_specs=[
-            pl.BlockSpec((2,), lambda i: (0,)),
-            pl.BlockSpec((rows, c_outer, LANES), lambda i: (0, 0, 0)),
+            SMEM_SPEC,
+            pl.BlockSpec((rows, LANES, c_outer), lambda i: (0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((block,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n_blocks * block,), jnp.float32),
+        out_specs=pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_blocks * BLOCK_ROWS, LANES),
+                                       jnp.float32),
         interpret=interpret,
-    )(off.astype(U32), table.reshape(rows, c_outer, LANES))
-    return out[:n]
+    )(off.astype(U32), table_t.astype(jnp.float32))
+    return out.reshape(-1)[:n]
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("offset", "n", "key", "block", "interpret"))
+                   static_argnames=("offset", "n", "key", "interpret"))
 def sketch_estimate(table: jax.Array, offset: int, n: int, key: int = 0, *,
-                    block: int = 512, interpret: bool = False) -> jax.Array:
+                    interpret: bool = False) -> jax.Array:
     """Pallas decode: median-of-rows estimates for ids offset..offset+n."""
     off = jnp.array([offset & 0xFFFFFFFF, offset >> 32], dtype=U32)
-    return sketch_estimate_words(table, off, n, key, block=block,
-                                 interpret=interpret)
+    return sketch_estimate_words(table, off, n, key, interpret=interpret)

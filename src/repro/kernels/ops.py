@@ -177,6 +177,16 @@ def _mode(path: str, interpret: bool) -> str:
     return "interpret" if (path == "pallas" and interpret) else "compiled"
 
 
+def resolve_ops(impl: str, rows: int, cols: int) -> dict[str, str]:
+    """``path:mode`` each sketch op of the train step resolves to here."""
+    out = {}
+    for op, fused in (("encode", False), ("estimate", False),
+                      ("momentum_error", True), ("topk_mask", True)):
+        path, interp = _resolve(impl, rows, cols, fused)
+        out[op] = f"{path}:{_mode(path, interp)}"
+    return out
+
+
 def sketch_encode(values: jax.Array, offset: int, rows: int, cols: int,
                   key: int = 0, *, impl: str = "auto") -> jax.Array:
     """(rows, cols) sketch contribution of a chunk."""

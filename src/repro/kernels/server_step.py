@@ -33,11 +33,13 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import count_sketch as cs
 from repro.core import hashing
 
-from .count_sketch import LANES, U32
+from .count_sketch import (BLOCK_ROWS, HIGHEST, LANES, SMEM_SPEC, U32,
+                           lane_dense, onehots_t, scatter_tile)
 
 
 # -- jnp reference algebra (bitwise = the unfused server_step) ---------------
@@ -92,8 +94,10 @@ def momentum_error(agg: jax.Array, su: jax.Array, se: jax.Array, lr,
                          f"got {cols}")
     lr_arr = jnp.asarray(lr, jnp.float32).reshape(1)
     out_sds = jax.ShapeDtypeStruct((rows, cols), jnp.float32)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     return pl.pallas_call(
         functools.partial(_momentum_error_kernel, momentum=momentum),
+        in_specs=[SMEM_SPEC, vmem, vmem, vmem],
         out_shape=(out_sds, out_sds),
         interpret=interpret,
     )(lr_arr, agg.astype(jnp.float32), su.astype(jnp.float32),
@@ -102,7 +106,7 @@ def momentum_error(agg: jax.Array, su: jax.Array, se: jax.Array, lr,
 
 def _topk_mask_kernel(hi_ref, lo_ref, val_ref, su_ref, se_ref,
                       su_out, se_out, hit_out, delta_out, *, rows: int,
-                      cols: int, key: int, block: int, k: int,
+                      cols: int, key: int, k: int,
                       error_mode: str, momentum_masking: bool,
                       n_blocks: int):
     pid = pl.program_id(0)
@@ -114,33 +118,33 @@ def _topk_mask_kernel(hi_ref, lo_ref, val_ref, su_ref, se_ref,
         hit_out[...] = jnp.zeros_like(hit_out)
         delta_out[...] = jnp.zeros_like(delta_out)
 
-    # padded id slots must not hash: zero their one-hot rows entirely
-    start = pid * block
-    valid = ((jax.lax.broadcasted_iota(jnp.int32, (block,), 0) + start)
-             < k).astype(jnp.float32)
+    # padded id slots must not hash: zero their one-hot columns entirely
+    shape = (BLOCK_ROWS, LANES)
+    slot = (pid * BLOCK_ROWS * LANES
+            + jax.lax.broadcasted_iota(jnp.int32, shape, 0) * LANES
+            + jax.lax.broadcasted_iota(jnp.int32, shape, 1))
+    valid = (slot < k).astype(jnp.float32)
     hi = hi_ref[...]
     lo = lo_ref[...]
     v = val_ref[...].astype(jnp.float32)
     c_outer = cols // LANES
-    outer_iota = jax.lax.broadcasted_iota(jnp.int32, (block, c_outer), 1)
-    lane_iota = jax.lax.broadcasted_iota(jnp.int32, (block, LANES), 1)
     for j in range(rows):
         idx = hashing.bucket_hash(lo, hi, j, cols, key)
-        outer = (idx // LANES)[:, None]
-        lane = (idx % LANES)[:, None]
-        onehot_outer = ((outer_iota == outer).astype(jnp.float32)
-                        * valid[:, None])                          # (B, C_o)
-        lane_onehot = (lane_iota == lane).astype(jnp.float32)      # (B, 128)
+        sv = hashing.sign_hash(lo, hi, j, key) * v
+        hit = delta = None
+        for r in range(BLOCK_ROWS):
+            o_t, l_t = onehots_t(idx[r:r + 1, :], c_outer)
+            o_t = o_t * valid[r:r + 1, :]                          # (C_o, 128)
+            if need_hit:
+                t = scatter_tile(o_t, l_t)
+                hit = t if hit is None else hit + t
+            if need_delta:
+                t = scatter_tile(o_t, l_t * sv[r:r + 1, :], HIGHEST)
+                delta = t if delta is None else delta + t
         if need_hit:
-            hit_out[j, :, :] += jax.lax.dot_general(
-                onehot_outer, lane_onehot, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)                # (C_o, 128)
+            hit_out[j, :, :] += hit
         if need_delta:
-            sgn = hashing.sign_hash(lo, hi, j, key)
-            vl = lane_onehot * (sgn * v)[:, None]
-            delta_out[j, :, :] += jax.lax.dot_general(
-                onehot_outer, vl, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+            delta_out[j, :, :] += delta
 
     @pl.when(pid == n_blocks - 1)
     def _apply():
@@ -158,7 +162,7 @@ def _topk_mask_kernel(hi_ref, lo_ref, val_ref, su_ref, se_ref,
 
 def topk_mask(su: jax.Array, se: jax.Array, hi: jax.Array, lo: jax.Array,
               values: jax.Array, key: int = 0, *, error_mode: str = "zero",
-              momentum_masking: bool = True, block: int = 256,
+              momentum_masking: bool = True,
               interpret: bool = False) -> tuple[jax.Array, jax.Array]:
     """Fused post-extraction update — one Pallas call over the id blocks.
 
@@ -166,6 +170,7 @@ def topk_mask(su: jax.Array, se: jax.Array, hi: jax.Array, lo: jax.Array,
     the S(Delta) table) across the grid in VMEM-resident out buffers, then
     the final grid step applies zeroing/subtraction to ``se`` and masking
     to ``su`` in place — the tables are read and written exactly once.
+    The ids and values arrive lane-dense, as ``(k_pad / 128, 128)`` views.
     """
     rows, cols = su.shape
     if cols % LANES != 0:
@@ -175,38 +180,27 @@ def topk_mask(su: jax.Array, se: jax.Array, hi: jax.Array, lo: jax.Array,
         raise ValueError(f"bad error_mode {error_mode}")
     k = hi.shape[0]
     if k == 0:
-        # no extracted ids: nothing hits, nothing is subtracted.  The grid
-        # below always launches >= 1 step, whose BlockSpec would read a
-        # full (block,) window from the zero-length id arrays.
+        # no extracted ids: nothing hits, nothing is subtracted.
         return su.astype(jnp.float32), se.astype(jnp.float32)
-    n_pad = (-k) % block
-    if n_pad:
-        pad_u = jnp.zeros((n_pad,), U32)
-        hi = jnp.concatenate([hi.astype(U32), pad_u])
-        lo = jnp.concatenate([lo.astype(U32), pad_u])
-        values = jnp.concatenate([values.astype(jnp.float32),
-                                  jnp.zeros((n_pad,), jnp.float32)])
-    n_blocks = max(1, (k + n_pad) // block)
+    hi2 = lane_dense(hi.astype(U32))
+    lo2 = lane_dense(lo.astype(U32))
+    v2 = lane_dense(values.astype(jnp.float32))
+    n_blocks = hi2.shape[0] // BLOCK_ROWS
     c_outer = cols // LANES
     table_sds = jax.ShapeDtypeStruct((rows, c_outer, LANES), jnp.float32)
     table_spec = pl.BlockSpec((rows, c_outer, LANES), lambda i: (0, 0, 0))
+    id_spec = pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0))
     su_o, se_o, _, _ = pl.pallas_call(
         functools.partial(_topk_mask_kernel, rows=rows, cols=cols, key=key,
-                          block=block, k=k, error_mode=error_mode,
+                          k=k, error_mode=error_mode,
                           momentum_masking=momentum_masking,
                           n_blocks=n_blocks),
         grid=(n_blocks,),
-        in_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            table_spec,
-            table_spec,
-        ],
+        in_specs=[id_spec, id_spec, id_spec, table_spec, table_spec],
         out_specs=(table_spec, table_spec, table_spec, table_spec),
         out_shape=(table_sds, table_sds, table_sds, table_sds),
         interpret=interpret,
-    )(hi.astype(U32), lo.astype(U32), values.astype(jnp.float32),
+    )(hi2, lo2, v2,
       su.astype(jnp.float32).reshape(rows, c_outer, LANES),
       se.astype(jnp.float32).reshape(rows, c_outer, LANES))
     return su_o.reshape(rows, cols), se_o.reshape(rows, cols)

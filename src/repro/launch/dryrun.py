@@ -51,8 +51,12 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
     if cfg_overrides:
         import dataclasses as _dc
         cfg = _dc.replace(cfg, **cfg_overrides)
-    mesh = mesh_lib.make_production_mesh(multi_pod=multi_pod)
-    mesh_name = "2x16x16" if multi_pod else "16x16"
+    # v5e pods: 16x16 = 256 chips; two pods add a leading ``pod`` axis
+    if multi_pod:
+        mesh = mesh_lib.make_mesh((2, 16, 16), ("pod", "data", "model"))
+    else:
+        mesh = mesh_lib.make_mesh((16, 16), ("data", "model"))
+    mesh_name = "x".join(str(s) for s in mesh.shape.values())
     fs_cfg = fs_cfg or default_fetchsgd_config()
 
     t0 = time.time()

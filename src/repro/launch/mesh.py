@@ -1,32 +1,34 @@
-"""Production mesh construction.
+"""Mesh construction.
 
-``make_production_mesh`` is a function (never a module-level constant) so
-importing this module never touches jax device state — the dry-run sets
+Every mesh of the repo is built by :func:`make_mesh`, with **Auto** axis
+types.  (``jax.make_mesh`` defaults to Explicit axes, and then
+``with_sharding_constraint`` may name no axis of the mesh, so the step's
+activation constraint fails to trace.)
+
+The builders are functions (never module-level constants) so importing
+this module never touches jax device state — the dry-run sets
 ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` *before* any jax
-import, and smoke tests/benches must keep seeing the real single device.
-
-Target hardware: TPU v5e pods, 16x16 = 256 chips per pod; the multi-pod
-mesh adds a leading ``pod`` axis (2 pods = 512 chips).
+import, and smoke tests/benches must keep seeing the real devices.
 """
 
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+def make_mesh(shape, axis_names):
+    """A mesh over the first ``prod(shape)`` devices, every axis Auto."""
+    shape, axis_names = tuple(shape), tuple(axis_names)
+    return jax.make_mesh(shape, axis_names,
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
-def make_debug_mesh(data: int = 1, model: int = 1):
-    """Tiny mesh over however many (host) devices exist — tests only."""
-    n = len(jax.devices())
-    if data * model > n:
-        raise ValueError(f"debug mesh {data}x{model} needs {data*model} "
-                         f"devices, have {n}")
-    return jax.make_mesh((data, model), ("data", "model"))
+def make_production_mesh():
+    """The devices present, data-parallel: ``data = device count``,
+    ``model = 1`` (one client per chip; the train step is then manual over
+    every axis)."""
+    return make_mesh((len(jax.devices()), 1), ("data", "model"))
 
 
 # TPU v5e per-chip constants used by the roofline report (see EXPERIMENTS.md)

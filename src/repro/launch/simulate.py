@@ -25,6 +25,7 @@ from repro.core import topk as TK
 from repro.data import federated, synthetic
 from repro.models import transformer
 from repro.optim import triangular
+from repro.xla_env import enable_compile_cache
 
 
 @dataclasses.dataclass
@@ -301,6 +302,7 @@ def main(argv=None):
                     help="emit sketch-health diagnostics every N rounds "
                          "(0 = never; only active with --metrics)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.population is not None and args.population < 1:
         ap.error(f"--population must be >= 1, got {args.population}")

@@ -3,7 +3,9 @@
 Every step is one ``jax.shard_map`` **manual over the batch/client axes**
 (``pod``, ``data``) and **auto (GSPMD) over ``model``** — tensor-parallel
 math inside each client cohort is untouched XLA, while FetchSGD's
-aggregation boundary is explicit:
+aggregation boundary is explicit.  A mesh whose ``model`` axis is 1 is
+manual over every axis, so no compiled Pallas (Mosaic) call is left to
+GSPMD, which cannot partition one:
 
     local grad -> sketch (r x c) -> psum over (pod, data) -> server update
 
@@ -38,32 +40,19 @@ from .shapes import ShapeSpec
 CACHE_DTYPE = jnp.bfloat16
 
 
-def _shard_map(f, *, mesh, in_specs, out_specs, axis_names, check_vma=False):
-    """jax.shard_map with a fallback to the pre-0.5 experimental API.
-
-    Old jax exposes shard_map under jax.experimental with ``check_rep``
-    instead of ``check_vma`` and an ``auto`` set (the complement of
-    ``axis_names``) instead of the manual-axis set.  There the Shardy
-    partitioner must also be switched on explicitly: the default GSPMD
-    partitioner check-fails (``sharding.IsManualSubgroup()``) on
-    ``lax.scan`` inside a partially-auto region, which every train step
-    hits via ``sketch_grads``.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=axis_names,
-                             check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map
-    jax.config.update("jax_use_shardy_partitioner", True)
-    auto = frozenset(mesh.axis_names) - set(axis_names)
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=check_vma, auto=auto)
-
-
 # -- plumbing --------------------------------------------------------------------
 
 def manual_axes(mesh) -> tuple[str, ...]:
+    """The client axes: the optimizer's collectives reduce over these."""
     return tuple(ax for ax in ("pod", "data") if ax in mesh.shape)
+
+
+def step_axes(mesh) -> set[str]:
+    """Mesh axes the step bodies are manual over: all of them when the
+    ``model`` axis is 1, else the client axes (``model`` left to GSPMD)."""
+    if mesh.shape.get("model", 1) == 1:
+        return set(mesh.axis_names)
+    return set(manual_axes(mesh))
 
 
 def _manual_only(spec: P, axes: tuple[str, ...]) -> P:
@@ -209,6 +198,13 @@ def make_train_step(cfg: ArchConfig, shape: ShapeSpec, mesh,
     # impl cannot run here — e.g. compiled Pallas on a CPU backend
     from repro.kernels import ops as kernel_ops
     kernel_ops.require_impl(fs_cfg.impl)
+    paths = kernel_ops.resolve_ops(fs_cfg.impl, fs_cfg.rows, fs_cfg.cols)
+    if mesh.shape["model"] > 1 and "pallas:compiled" in paths.values():
+        raise ValueError(
+            f"compiled Pallas sketch kernels ({paths}) need a step that is "
+            f"manual over every mesh axis, but model={mesh.shape['model']} "
+            f"is left to GSPMD, which cannot partition a Mosaic kernel.  Use "
+            f"a mesh with model=1 or sketch impl 'jnp'.")
     if weighted and aggregate not in ("sketch", "tree"):
         raise ValueError("weighted merging needs aggregate='sketch'|'tree' "
                          f"(got {aggregate!r})")
@@ -233,7 +229,7 @@ def make_train_step(cfg: ArchConfig, shape: ShapeSpec, mesh,
     ep_axis = "data" if has_ep else None
 
     act_sh = None
-    if cfg.d_model % mesh.shape["model"] == 0:
+    if "model" not in step_axes(mesh) and cfg.d_model % mesh.shape["model"] == 0:
         act_sh = NamedSharding(mesh, P(None, None, "model"))
 
     def _loss_grads(params, batch):
@@ -321,18 +317,18 @@ def make_train_step(cfg: ArchConfig, shape: ShapeSpec, mesh,
             view_sh, ml_modes, ml_specs, p_manual, b_manual, opt_spec,
             p_structs)
     elif aggregate == "async":
-        sm = _shard_map(
+        sm = jax.shard_map(
             body_async, mesh=mesh,
             in_specs=(p_manual, opt_spec, b_manual, P(), P(), P(), P()),
             out_specs=(p_manual, opt_spec, {"loss": P(), "table": P()}),
-            axis_names=set(axes), check_vma=False)
+            axis_names=step_axes(mesh), check_vma=False)
     else:
         w_specs = (P(axes),) if weighted else ()
-        sm = _shard_map(
+        sm = jax.shard_map(
             body, mesh=mesh,
             in_specs=(p_manual, opt_spec, b_manual, P()) + w_specs,
             out_specs=(p_manual, opt_spec, {"loss": P()}),
-            axis_names=set(axes), check_vma=False)
+            axis_names=step_axes(mesh), check_vma=False)
     # donation aliases params/opt in production (TPU); the CPU runtime
     # deadlocks on donated collective inputs, so tests run donate=False and
     # the dry-run (compile-only) sets donate=True to model real aliasing.
@@ -413,12 +409,12 @@ def make_prefill_step(cfg: ArchConfig, shape: ShapeSpec, mesh,
             logits, new_cache = transformer.prefill(params, batch, cfg, cache)
         return logits, new_cache
 
-    sm = _shard_map(
+    sm = jax.shard_map(
         body, mesh=mesh,
         in_specs=(_specs(p_shard, axes), _specs(b_shard, axes),
                   _specs(c_shard, axes)),
         out_specs=(logits_spec, _specs(c_shard, axes)),
-        axis_names=set(axes), check_vma=False)
+        axis_names=step_axes(mesh), check_vma=False)
     fn = jax.jit(sm, donate_argnums=(2,)) if donate else jax.jit(sm)
     return StepBundle(fn=fn, inputs=(p_sds, b_sds, c_sds))
 
@@ -441,12 +437,12 @@ def make_decode_step(cfg: ArchConfig, shape: ShapeSpec, mesh,
                                                         cache)
         return logits, new_cache
 
-    sm = _shard_map(
+    sm = jax.shard_map(
         body, mesh=mesh,
         in_specs=(_specs(p_shard, axes), _specs(b_shard, axes)["tokens"],
                   _specs(c_shard, axes)),
         out_specs=(logits_spec, _specs(c_shard, axes)),
-        axis_names=set(axes), check_vma=False)
+        axis_names=step_axes(mesh), check_vma=False)
     fn = jax.jit(sm, donate_argnums=(2,)) if donate else jax.jit(sm)
     return StepBundle(fn=fn, inputs=(p_sds, b_sds["tokens"], c_sds))
 
@@ -496,9 +492,9 @@ def _model_local_pipeline(cfg, mesh, axes, fs_cfg, layout, has_ep, ep_axis,
     g_out_specs = tuple(
         P(sa if sa else None, *spec)
         for sa, spec in zip(stack_axes, p_manual_leaves))
-    sm_grads = _shard_map(
+    sm_grads = jax.shard_map(
         grads_body, mesh=mesh, in_specs=(p_manual, b_manual),
-        out_specs=(P(), g_out_specs), axis_names=set(axes), check_vma=False)
+        out_specs=(P(), g_out_specs), axis_names=step_axes(mesh), check_vma=False)
 
     ml_spec_leaves = jax.tree_util.tree_leaves(
         ml_spec_tree, is_leaf=lambda x: isinstance(x, P))
@@ -516,7 +512,7 @@ def _model_local_pipeline(cfg, mesh, axes, fs_cfg, layout, has_ep, ep_axis,
         tbl = jax.lax.psum(tbl, ("model",))
         return jax.lax.pmean(tbl, axes)
 
-    sm_sketch = _shard_map(
+    sm_sketch = jax.shard_map(
         sketch_body, mesh=mesh, in_specs=s_in_specs, out_specs=P(),
         axis_names=set(axes) | {"model"}, check_vma=False)
 
@@ -528,11 +524,11 @@ def _model_local_pipeline(cfg, mesh, axes, fs_cfg, layout, has_ep, ep_axis,
                                    local=has_ep, view_shardings=view_sh)
         return new_params, new_state
 
-    sm_server = _shard_map(
+    sm_server = jax.shard_map(
         server_body, mesh=mesh,
         in_specs=(p_manual, opt_spec, P(), P()),
         out_specs=(p_manual, opt_spec),
-        axis_names=set(axes), check_vma=False)
+        axis_names=step_axes(mesh), check_vma=False)
 
     def fn(params, opt_state, batch, lr):
         loss, g_stacked = sm_grads(params, batch)

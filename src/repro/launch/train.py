@@ -1,9 +1,10 @@
 """Mesh training driver: FetchSGD on the distributed step builders.
 
-On real hardware this runs the production mesh; in this container it runs
-a debug mesh over forced host devices, exercising the same shard_map path
-as the dry-run.  (For laptop-scale experiments use
-``examples/train_federated_lm.py`` — same optimizer, no mesh.)
+Without ``--debug-mesh`` the mesh is the devices present, one client per
+chip (data = device count, model = 1); ``--debug-mesh`` rehearses a mesh
+on forced host devices of the CPU, through the same shard_map path.
+(For laptop-scale experiments use ``examples/train_federated_lm.py`` —
+same optimizer, no mesh.)
 
 Aggregation goes through the federation runtime (``repro.fed``):
 ``--aggregate flat`` is one pmean, ``tree`` reduces hierarchically per
@@ -17,7 +18,7 @@ is the full-gradient-psum baseline.
 
 import sys
 
-from repro.xla_env import debug_mesh_devices
+from repro.xla_env import debug_mesh_devices, enable_compile_cache
 
 debug_mesh_devices(sys.argv)  # must precede the first jax import
 
@@ -45,7 +46,6 @@ def main():
                     help="use the reduced config (CPU-friendly)")
     ap.add_argument("--debug-mesh", default=None,
                     help="e.g. 4x2 = (data=4, model=2) host-device mesh")
-    ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--rounds", type=int, default=5)
     ap.add_argument("--seq-len", type=int, default=64)
     ap.add_argument("--global-batch", type=int, default=8)
@@ -78,16 +78,16 @@ def main():
                          "default_rng (pre-knob checkpoint compatible)")
     obs.add_cli_flags(ap)   # --metrics PATH.jsonl / --trace / --obs-summary
     args = ap.parse_args()
+    enable_compile_cache()
     tele = obs.from_args(args, run="train", arch=args.arch,
                          aggregate=args.aggregate, clock=args.clock)
 
     if args.debug_mesh:
         parts = [int(p) for p in args.debug_mesh.split("x")]
-        mesh = jax.make_mesh(tuple(parts),
-                             ("data", "model") if len(parts) == 2
-                             else ("pod", "data", "model"))
+        mesh = mesh_lib.make_mesh(parts, ("data", "model") if len(parts) == 2
+                                  else ("pod", "data", "model"))
     else:
-        mesh = mesh_lib.make_production_mesh(multi_pod=args.multi_pod)
+        mesh = mesh_lib.make_production_mesh()
 
     cfg = (configs.get_smoke(args.arch) if args.smoke
            else configs.get_config(args.arch))

@@ -132,9 +132,7 @@ def moe_apply_ep(p: dict, x: jax.Array, cfg: ArchConfig, axis: str):
     """
     B, S, d = x.shape
     E, K = cfg.n_experts, cfg.expert_top_k
-    # jax.lax.axis_size is missing pre-0.5; psum(1) is the portable spelling
-    ep = (jax.lax.axis_size(axis) if hasattr(jax.lax, "axis_size")
-          else jax.lax.psum(1, axis))
+    ep = jax.lax.axis_size(axis)
     E_loc = p["w_gate"].shape[0]           # local experts
     assert E_loc * ep == E, (E_loc, ep, E)
     T = B * S

@@ -20,7 +20,8 @@ def run_sub(code: str):
          "import os\n"
          "os.environ['XLA_FLAGS'] = "
          "'--xla_force_host_platform_device_count=4'\n"
-         "import sys\nsys.path.insert(0, 'src')\n" + prog],
+         "import sys\nsys.path.insert(0, 'src')\n"
+         "from repro.launch.mesh import make_mesh\n" + prog],
         capture_output=True, text=True, timeout=TIMEOUT, cwd=".")
     assert proc.returncode == 0, proc.stderr[-3000:]
     return proc.stdout
@@ -36,7 +37,7 @@ def test_train_step_runs_and_matches_single_host():
         from repro.core import fetchsgd as F, layout as L
         from repro.launch import shapes, steps
         from repro.models import transformer
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         cfg = configs.get_smoke("internlm2-1.8b")
         fs = F.FetchSGDConfig(rows=3, cols=4096, k=64, momentum=0.9)
         bundle = steps.make_train_step(
@@ -78,7 +79,7 @@ def test_weighted_train_step_matches_weighted_reference():
         from repro.core import fetchsgd as F, layout as L
         from repro.launch import shapes, steps
         from repro.models import transformer
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         cfg = configs.get_smoke("internlm2-1.8b")
         fs = F.FetchSGDConfig(rows=3, cols=4096, k=64, momentum=0.9)
         params = transformer.init_params(cfg, jax.random.PRNGKey(0))
@@ -131,7 +132,7 @@ def test_decode_and_prefill_compile_and_run():
         from repro import configs
         from repro.launch import shapes, steps
         from repro.models import transformer
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         cfg = configs.get_smoke("glm4-9b")
         params = transformer.init_params(cfg, jax.random.PRNGKey(0))
         bp = steps.make_prefill_step(cfg, shapes.ShapeSpec("p", "prefill", 32, 4), mesh)
@@ -158,7 +159,7 @@ def test_expert_parallel_all_to_all_matches_local():
         from repro.models import moe
         cfg = dataclasses.replace(configs.get_smoke("jamba-v0.1-52b"),
                                   shard_experts_data=True, capacity_factor=4.0)
-        mesh = jax.make_mesh((4, 1), ("data", "model"))
+        mesh = make_mesh((4, 1), ("data", "model"))
         p = moe.moe_init(jax.random.PRNGKey(0), cfg)
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, cfg.d_model))
         ref, _ = moe._moe_apply_local(p, x, cfg)
@@ -172,8 +173,7 @@ def test_expert_parallel_all_to_all_matches_local():
                  "w_down": P("data")}
         if "shared" in p:
             espec["shared"] = jax.tree.map(lambda _: P(), p["shared"])
-        from repro.launch.steps import _shard_map
-        f = jax.jit(_shard_map(body, mesh=mesh,
+        f = jax.jit(jax.shard_map(body, mesh=mesh,
                     in_specs=(espec, P("data")), out_specs=P("data"),
                     axis_names={"data"}, check_vma=False))
         with mesh:
@@ -182,3 +182,23 @@ def test_expert_parallel_all_to_all_matches_local():
         print("REL_ERR", err)
         assert err < 2e-2, err
     """)
+
+
+def test_compiled_pallas_with_model_axis_raises(monkeypatch):
+    """A model axis > 1 leaves the sketch kernels to GSPMD, which cannot
+    partition a Mosaic call: the build refuses instead of falling back."""
+    from jax.sharding import AbstractMesh
+    from repro import configs
+    from repro.core import fetchsgd as F
+    from repro.kernels import ops
+    from repro.launch import shapes, steps
+    monkeypatch.setattr(ops, "pallas_compile_supported", lambda: True)
+    cfg = configs.get_smoke("gpt2s-federated")
+    shape = shapes.ShapeSpec("t", "train", 32, 4)
+    fs = F.FetchSGDConfig(rows=3, cols=4096, k=64)
+    with pytest.raises(ValueError, match="cannot partition a Mosaic kernel"):
+        steps.make_train_step(cfg, shape, AbstractMesh((2, 2), ("data", "model")), fs)
+    assert steps.step_axes(AbstractMesh((4, 1), ("data", "model"))) == {
+        "data", "model"}
+    assert steps.step_axes(AbstractMesh((2, 2), ("data", "model"))) == {
+        "data"}

@@ -53,6 +53,16 @@ def test_estimate_matches_ref(rng, n, rows, cols):
     np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("rows", range(1, 10))
+def test_median_rows_matches_jnp_median(rng, rows):
+    """The kernel's sort-free median equals ``jnp.median`` over the rows,
+    ties and repeated values included."""
+    x = rng.integers(-3, 4, size=(rows, 512)).astype(np.float32)
+    x[:, :256] = rng.normal(size=(rows, 256))
+    got = pk.median_rows([jnp.asarray(r) for r in x])
+    np.testing.assert_array_equal(got, jnp.median(jnp.asarray(x), axis=0))
+
+
 @pytest.mark.parametrize("offset", [0, 2**31 - 5, 2**32 - 3, 2**41 + 99])
 def test_encode_64bit_offsets(rng, offset):
     """Hash identity must survive the 32-bit word boundary (d ~ 4e11)."""

@@ -278,17 +278,17 @@ def test_weighted_mesh_aggregate_single_device():
     import jax
     from jax.sharding import PartitionSpec as P
     from repro.fed import mesh_aggregate
-    from repro.launch.steps import _shard_map
-    mesh = jax.make_mesh((1,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("data",))
     t = jnp.full((3, 4), 5.0)
     w = jnp.asarray([2.0])
 
     def body(t, w):
         return mesh_aggregate(t, ("data",), "tree", weight=w[0])
 
-    out = jax.jit(_shard_map(body, mesh=mesh, in_specs=(P(), P("data")),
-                             out_specs=P(), axis_names={"data"},
-                             check_vma=False))(t, w)
+    out = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P(), P("data")),
+                                out_specs=P(), axis_names={"data"},
+                                check_vma=False))(t, w)
     np.testing.assert_allclose(np.asarray(out), 5.0, rtol=1e-6)
 
 
