@@ -1,0 +1,14 @@
+"""``named_forward_ms``: device time per round of the client model's forward
+pass, by the program's names: the ops of the ``client_model`` scope
+(``launch.steps``, around ``value_and_grad``) whose path holds no
+``transpose(jvp(``.
+
+Layer: client model. Moves ``round_s``. Read through ``layer_map``; nothing
+where no such op ran or the program names no layers.
+"""
+
+import layer_map
+
+
+def read(ctx):
+    return layer_map.read(ctx, "forward")

@@ -33,6 +33,7 @@ from . import count_sketch as cs
 from . import layout as layout_lib
 from . import topk as topk_lib
 from repro.kernels import ops as kernel_ops
+from repro.obs import layers
 
 
 @dataclasses.dataclass(frozen=True)
@@ -146,18 +147,22 @@ def server_step(agg_table: jax.Array, state: FetchSGDState, lr: jax.Array,
     server_step``); with ``jnp`` the same algebra runs as XLA ops and is
     bitwise identical to :func:`server_step_reference` (pinned in
     ``tests/test_server_step.py``).
+
+    Traced under the ``server_state`` scope; the unsketch and top-k inside
+    name their own (``repro.obs.layers``).
     """
-    su, se = kernel_ops.fused_momentum_error(
-        agg_table, state.momentum_sketch, state.error_sketch, lr,
-        cfg.momentum, impl=cfg.impl)
-    delta = unsketch_topk(se, layout, cfg)
-    hi, lo = topk_lib.global_ids(delta, layout)
-    su, se = kernel_ops.fused_topk_mask(
-        su, se, hi, lo, delta.values, cfg.hash_key,
-        error_mode=cfg.error_mode, momentum_masking=cfg.momentum_masking,
-        impl=cfg.impl)
-    new_state = FetchSGDState(momentum_sketch=su, error_sketch=se,
-                              step=state.step + 1)
+    with jax.named_scope(layers.SERVER_STATE):
+        su, se = kernel_ops.fused_momentum_error(
+            agg_table, state.momentum_sketch, state.error_sketch, lr,
+            cfg.momentum, impl=cfg.impl)
+        delta = unsketch_topk(se, layout, cfg)
+        hi, lo = topk_lib.global_ids(delta, layout)
+        su, se = kernel_ops.fused_topk_mask(
+            su, se, hi, lo, delta.values, cfg.hash_key,
+            error_mode=cfg.error_mode,
+            momentum_masking=cfg.momentum_masking, impl=cfg.impl)
+        new_state = FetchSGDState(momentum_sketch=su, error_sketch=se,
+                                  step=state.step + 1)
     return delta, new_state
 
 

@@ -29,6 +29,7 @@ import numpy as np
 
 from . import hashing
 from . import layout as layout_lib
+from repro.obs import layers
 
 EXACT_CHUNK_LIMIT = 64   # <= this many chunks: keep per-chunk k exact
 
@@ -58,37 +59,42 @@ def topk_from_sketch(table: jax.Array, layout: layout_lib.ParamLayout,
     ``impl`` selects the row-estimate kernel (``repro.kernels.ops``): the
     per-chunk U(.) gather is the decode hot spot, so the Pallas estimate
     kernel slots in here while the candidate ``lax.top_k`` stays XLA.
+
+    Traced under the ``topk`` scope, the row estimate under ``unsketch``
+    inside it (``repro.obs.layers``).
     """
     from repro.kernels import ops as kernel_ops
-    rows, cols = table.shape
-    nall = layout.num_chunks
-    cand_vals, cand_local, cand_chunk = [], [], []
-    for g in layout.groups:
-        size = g.n_rows * g.row_len
-        kk = _chunk_k(k, size, nall)
-        offs = [layout.chunks[ci].offset for ci in g.chunk_ids]
-        lo_t, hi_t = hashing.offset_words(offs)
-        cid_t = jnp.asarray(g.chunk_ids, jnp.int32)
+    with jax.named_scope(layers.TOPK):
+        rows, cols = table.shape
+        nall = layout.num_chunks
+        cand_vals, cand_local, cand_chunk = [], [], []
+        for g in layout.groups:
+            size = g.n_rows * g.row_len
+            kk = _chunk_k(k, size, nall)
+            offs = [layout.chunks[ci].offset for ci in g.chunk_ids]
+            lo_t, hi_t = hashing.offset_words(offs)
+            cid_t = jnp.asarray(g.chunk_ids, jnp.int32)
 
-        def body(off):
-            lo, hi, cid = off
-            est = kernel_ops.sketch_estimate_words(table, lo, hi, size, key,
-                                                   impl=impl)
-            _, idx = jax.lax.top_k(jnp.abs(est), kk)
-            return est[idx], idx.astype(jnp.int32), jnp.full((kk,), cid,
-                                                             jnp.int32)
+            def body(off):
+                lo, hi, cid = off
+                with jax.named_scope(layers.UNSKETCH):
+                    est = kernel_ops.sketch_estimate_words(
+                        table, lo, hi, size, key, impl=impl)
+                _, idx = jax.lax.top_k(jnp.abs(est), kk)
+                return (est[idx], idx.astype(jnp.int32),
+                        jnp.full((kk,), cid, jnp.int32))
 
-        v, li, ci = jax.lax.map(body, (lo_t, hi_t, cid_t))
-        cand_vals.append(v.reshape(-1))
-        cand_local.append(li.reshape(-1))
-        cand_chunk.append(ci.reshape(-1))
-    vals = jnp.concatenate(cand_vals)
-    local = jnp.concatenate(cand_local)
-    chunk = jnp.concatenate(cand_chunk)
-    k_eff = min(k, int(vals.shape[0]))
-    _, sel = jax.lax.top_k(jnp.abs(vals), k_eff)
-    return SparseDelta(chunk_id=chunk[sel], local_idx=local[sel],
-                       values=vals[sel], k=k_eff)
+            v, li, ci = jax.lax.map(body, (lo_t, hi_t, cid_t))
+            cand_vals.append(v.reshape(-1))
+            cand_local.append(li.reshape(-1))
+            cand_chunk.append(ci.reshape(-1))
+        vals = jnp.concatenate(cand_vals)
+        local = jnp.concatenate(cand_local)
+        chunk = jnp.concatenate(cand_chunk)
+        k_eff = min(k, int(vals.shape[0]))
+        _, sel = jax.lax.top_k(jnp.abs(vals), k_eff)
+        return SparseDelta(chunk_id=chunk[sel], local_idx=local[sel],
+                           values=vals[sel], k=k_eff)
 
 
 def topk_dense(acc_views: list, layout: layout_lib.ParamLayout,

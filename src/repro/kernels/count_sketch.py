@@ -44,6 +44,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import hashing
+from repro.obs import layers
 
 LANES = 128
 BLOCK_ROWS = 8            # sublanes of one f32 tile: 1024 elements per step
@@ -161,6 +162,7 @@ def sketch_encode_words(values: jax.Array, off: jax.Array, rows: int,
         out_specs=pl.BlockSpec((rows, c_outer, LANES), lambda i: (0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, c_outer, LANES), jnp.float32),
         interpret=interpret,
+        name=layers.ENCODE_KERNEL,
     )(off.astype(U32), v2)
     return out.reshape(rows, cols)
 
@@ -224,6 +226,7 @@ def sketch_estimate_words(table: jax.Array, off: jax.Array, n: int,
         out_shape=jax.ShapeDtypeStruct((n_blocks * BLOCK_ROWS, LANES),
                                        jnp.float32),
         interpret=interpret,
+        name=layers.ESTIMATE_KERNEL,
     )(off.astype(U32), table_t.astype(jnp.float32))
     return out.reshape(-1)[:n]
 

@@ -37,6 +37,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import count_sketch as cs
 from repro.core import hashing
+from repro.obs import layers
 
 from .count_sketch import (BLOCK_ROWS, HIGHEST, LANES, SMEM_SPEC, U32,
                            lane_dense, onehots_t, scatter_tile)
@@ -100,6 +101,7 @@ def momentum_error(agg: jax.Array, su: jax.Array, se: jax.Array, lr,
         in_specs=[SMEM_SPEC, vmem, vmem, vmem],
         out_shape=(out_sds, out_sds),
         interpret=interpret,
+        name=layers.MOMENTUM_ERROR_KERNEL,
     )(lr_arr, agg.astype(jnp.float32), su.astype(jnp.float32),
       se.astype(jnp.float32))
 
@@ -200,6 +202,7 @@ def topk_mask(su: jax.Array, se: jax.Array, hi: jax.Array, lo: jax.Array,
         out_specs=(table_spec, table_spec, table_spec, table_spec),
         out_shape=(table_sds, table_sds, table_sds, table_sds),
         interpret=interpret,
+        name=layers.TOPK_MASK_KERNEL,
     )(hi2, lo2, v2,
       su.astype(jnp.float32).reshape(rows, c_outer, LANES),
       se.astype(jnp.float32).reshape(rows, c_outer, LANES))

@@ -35,6 +35,7 @@ from repro.core import layout as layout_lib
 from repro.fed import aggregator as fed_agg
 from repro.models import moe, sharding, transformer
 from repro.models.config import ArchConfig
+from repro.obs import layers
 from .shapes import ShapeSpec
 
 CACHE_DTYPE = jnp.bfloat16
@@ -234,16 +235,23 @@ def make_train_step(cfg: ArchConfig, shape: ShapeSpec, mesh,
 
     def _loss_grads(params, batch):
         with moe.expert_parallel(ep_axis), \
-                sharding.activation_sharding(act_sh):
+                sharding.activation_sharding(act_sh), \
+                jax.named_scope(layers.CLIENT_MODEL):
             return jax.value_and_grad(
                 lambda p: transformer.loss_fn(p, batch, cfg)[0])(params)
+
+    def _sketch(grads, sidx):
+        with jax.named_scope(layers.SKETCH_ENCODE):
+            return F.sketch_grads(grads, layout, fs_cfg, shard_idx=sidx,
+                                  local=has_ep, view_shardings=view_sh)
 
     def _server_apply(params, opt_state, table, lr, sidx):
         delta, new_state = F.server_step(table, opt_state, lr, layout,
                                          fs_cfg)
-        new_params = F.apply_delta(params, layout, delta,
-                                   shard_idx=sidx, local=has_ep,
-                                   view_shardings=view_sh)
+        with jax.named_scope(layers.SPARSE_APPLY):
+            new_params = F.apply_delta(params, layout, delta,
+                                       shard_idx=sidx, local=has_ep,
+                                       view_shardings=view_sh)
         return new_params, new_state
 
     def body(params, opt_state, batch, lr, *maybe_w):
@@ -252,12 +260,12 @@ def make_train_step(cfg: ArchConfig, shape: ShapeSpec, mesh,
         if aggregate in ("sketch", "tree"):
             # FetchSGD: the ONLY cross-client collective is (rows x cols);
             # 'tree' reduces it hierarchically, one link class per level.
-            table = F.sketch_grads(grads, layout, fs_cfg,
-                                   shard_idx=sidx, local=has_ep,
-                                   view_shardings=view_sh)
-            table = fed_agg.mesh_aggregate(
-                table, axes, policy="tree" if aggregate == "tree" else "flat",
-                weight=maybe_w[0][0] if maybe_w else None)
+            table = _sketch(grads, sidx)
+            with jax.named_scope(layers.MERGE):
+                table = fed_agg.mesh_aggregate(
+                    table, axes,
+                    policy="tree" if aggregate == "tree" else "flat",
+                    weight=maybe_w[0][0] if maybe_w else None)
             new_params, new_state = _server_apply(params, opt_state, table,
                                                   lr, sidx)
         elif aggregate == "dense":
@@ -269,14 +277,15 @@ def make_train_step(cfg: ArchConfig, shape: ShapeSpec, mesh,
                 red = axes if path not in ds_axes else tuple(
                     a for a in axes if a != "data")
                 return jax.lax.pmean(g, red) if red else g
-            grads = jax.tree_util.tree_map_with_path(maybe_psum, grads)
-            table = F.sketch_grads(grads, layout, fs_cfg, shard_idx=sidx,
-                                   local=has_ep, view_shardings=view_sh)
+            with jax.named_scope(layers.MERGE):
+                grads = jax.tree_util.tree_map_with_path(maybe_psum, grads)
+            table = _sketch(grads, sidx)
             new_params, new_state = _server_apply(params, opt_state, table,
                                                   lr, sidx)
         else:
             raise ValueError(aggregate)
-        metrics = {"loss": jax.lax.pmean(loss, axes)}
+        with jax.named_scope(layers.MERGE):
+            metrics = {"loss": jax.lax.pmean(loss, axes)}
         return new_params, new_state, metrics
 
     def body_async(params, opt_state, batch, lr, fresh_w, inject_table,
@@ -294,18 +303,19 @@ def make_train_step(cfg: ArchConfig, shape: ShapeSpec, mesh,
         """
         loss, grads = _loss_grads(params, batch)
         sidx = jax.lax.axis_index("data") if has_ep else None
-        table = F.sketch_grads(grads, layout, fs_cfg, shard_idx=sidx,
-                               local=has_ep, view_shardings=view_sh)
-        fresh = fed_agg.mesh_aggregate(table, axes, policy="flat")
-        total_w = fresh_w + inject_w
-        merged = (fresh_w * fresh + inject_table) / jnp.maximum(total_w,
-                                                                1e-8)
+        table = _sketch(grads, sidx)
+        with jax.named_scope(layers.MERGE):
+            fresh = fed_agg.mesh_aggregate(table, axes, policy="flat")
+            total_w = fresh_w + inject_w
+            merged = (fresh_w * fresh + inject_table) / jnp.maximum(total_w,
+                                                                    1e-8)
         new_params, new_state = jax.lax.cond(
             total_w > 0,
             lambda ops: _server_apply(*ops, sidx),
             lambda ops: (ops[0], ops[1]),
             (params, opt_state, merged, lr))
-        metrics = {"loss": jax.lax.pmean(loss, axes), "table": fresh}
+        with jax.named_scope(layers.MERGE):
+            metrics = {"loss": jax.lax.pmean(loss, axes), "table": fresh}
         return new_params, new_state, metrics
 
     opt_spec = jax.tree.map(lambda _: P(), jax.eval_shape(
@@ -380,11 +390,13 @@ def make_cohort_fn(cfg: ArchConfig, layout, fs_cfg: F.FetchSGDConfig,
     def cohort_fn(params, tokens, labels):
         def one(tl):
             t, l = tl
-            (loss, _), grads = jax.value_and_grad(
-                lambda p: transformer.loss_fn(
-                    p, {"tokens": t, "labels": l}, cfg, remat=False),
-                has_aux=True)(params)
-            return loss, encode_fn(grads)
+            with jax.named_scope(layers.CLIENT_MODEL):
+                (loss, _), grads = jax.value_and_grad(
+                    lambda p: transformer.loss_fn(
+                        p, {"tokens": t, "labels": l}, cfg, remat=False),
+                    has_aux=True)(params)
+            with jax.named_scope(layers.SKETCH_ENCODE):
+                return loss, encode_fn(grads)
         return jax.lax.map(one, (tokens, labels))
 
     return cohort_fn
@@ -482,12 +494,15 @@ def _model_local_pipeline(cfg, mesh, axes, fs_cfg, layout, has_ep, ep_axis,
 
     def grads_body(params, batch):
         with moe.expert_parallel(ep_axis), \
-                sharding.activation_sharding(act_sh):
+                sharding.activation_sharding(act_sh), \
+                jax.named_scope(layers.CLIENT_MODEL):
             loss, grads = jax.value_and_grad(
                 lambda p: transformer.loss_fn(p, batch, cfg)[0])(params)
         g_leaves = jax.tree_util.tree_leaves(grads)
         stacked = [g[None] for g in g_leaves]
-        return jax.lax.pmean(loss, axes), tuple(stacked)
+        with jax.named_scope(layers.MERGE):
+            loss = jax.lax.pmean(loss, axes)
+        return loss, tuple(stacked)
 
     g_out_specs = tuple(
         P(sa if sa else None, *spec)
@@ -507,10 +522,12 @@ def _model_local_pipeline(cfg, mesh, axes, fs_cfg, layout, has_ep, ep_axis,
         grads = jax.tree_util.tree_unflatten(tdef, g_leaves)
         s_d = jax.lax.axis_index("data")
         s_m = jax.lax.axis_index("model")
-        tbl = model_local.sketch_grads(grads, layout, ml_plan, fs_cfg,
-                                       s_d, s_m)
-        tbl = jax.lax.psum(tbl, ("model",))
-        return jax.lax.pmean(tbl, axes)
+        with jax.named_scope(layers.SKETCH_ENCODE):
+            tbl = model_local.sketch_grads(grads, layout, ml_plan, fs_cfg,
+                                           s_d, s_m)
+            tbl = jax.lax.psum(tbl, ("model",))
+        with jax.named_scope(layers.MERGE):
+            return jax.lax.pmean(tbl, axes)
 
     sm_sketch = jax.shard_map(
         sketch_body, mesh=mesh, in_specs=s_in_specs, out_specs=P(),
@@ -520,8 +537,9 @@ def _model_local_pipeline(cfg, mesh, axes, fs_cfg, layout, has_ep, ep_axis,
         sidx = jax.lax.axis_index("data") if has_ep else None
         delta, new_state = F.server_step(table, opt_state, lr, layout,
                                          fs_cfg)
-        new_params = F.apply_delta(params, layout, delta, shard_idx=sidx,
-                                   local=has_ep, view_shardings=view_sh)
+        with jax.named_scope(layers.SPARSE_APPLY):
+            new_params = F.apply_delta(params, layout, delta, shard_idx=sidx,
+                                       local=has_ep, view_shardings=view_sh)
         return new_params, new_state
 
     sm_server = jax.shard_map(

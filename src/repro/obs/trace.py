@@ -17,6 +17,10 @@ properties matter for correctness of the numbers:
 Spans measure *host* wall-clock; they are meaningless inside a ``jit``
 trace (they would time tracing, not execution), so callers instrumenting
 dispatch-layer code must skip tracers (see ``repro.kernels.ops``).
+
+A live span is also a ``jax.profiler.TraceAnnotation`` of its name while it
+is open, so under ``jax.profiler`` it lands on the trace's host plane, on
+the device ops' clock: an idle gap of the device can be put down to it.
 """
 
 from __future__ import annotations
@@ -48,7 +52,8 @@ NULL_SPAN = NullSpan()
 class Span:
     """One live span; created by ``Telemetry.span`` only."""
 
-    __slots__ = ("_tele", "name", "attrs", "_t0", "_sync", "depth", "parent")
+    __slots__ = ("_tele", "name", "attrs", "_t0", "_sync", "depth", "parent",
+                 "_annotation")
 
     def __init__(self, tele, name: str, attrs: dict):
         self._tele = tele
@@ -58,6 +63,7 @@ class Span:
         self._sync = None
         self.depth = 0
         self.parent = None
+        self._annotation = None
 
     def sync(self, x):
         """Register a jax value/pytree to block on at exit; returns it."""
@@ -68,10 +74,13 @@ class Span:
         self.attrs.update(attrs)
 
     def __enter__(self):
+        import jax
         stack = self._tele._span_stack
         self.depth = len(stack)
         self.parent = stack[-1].name if stack else None
         stack.append(self)
+        self._annotation = jax.profiler.TraceAnnotation(self.name)
+        self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
@@ -80,6 +89,7 @@ class Span:
             import jax
             jax.block_until_ready(self._sync)
         dur = time.perf_counter() - self._t0
+        self._annotation.__exit__(exc_type, exc, tb)
         stack = self._tele._span_stack
         if stack and stack[-1] is self:
             stack.pop()

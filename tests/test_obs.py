@@ -154,6 +154,27 @@ class TestSpans:
         assert ev["error"] == "RuntimeError"
         assert tele._span_stack == []    # stack unwound despite the raise
 
+    def test_span_lands_on_the_profilers_host_plane(self, tmp_path):
+        """A live span is a profiler annotation too: on the trace's host
+        plane, on the clock of the device ops."""
+        import glob
+        import jax
+        import jax.numpy as jnp
+        tele = obs.Telemetry([obs.MemorySink()], trace=True)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with tele.span("train.step", round=0) as sp:
+                sp.sync(jnp.arange(4.0) * 2)
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                            recursive=True)
+        data = jax.profiler.ProfileData.from_file(path)
+        host = [ev.name for plane in data.planes
+                if plane.name.startswith("/host")
+                for line in plane.lines for ev in line.events]
+        assert "train.step" in host
+
 
 # ------------------------------------------------------------------ sinks
 

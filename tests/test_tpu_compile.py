@@ -14,6 +14,7 @@ every test file.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -107,3 +108,45 @@ def test_topk_mask_compiles(compile_tpu, error_mode):
         su, se, hi, lo, v, error_mode=error_mode),
         table, table, ((K,), jnp.uint32), ((K,), jnp.uint32),
         ((K,), jnp.float32))
+
+
+# -- the whole train step: its kernels and scopes name every layer -------------
+
+@pytest.fixture(scope="module")
+def step_text(topo, no_persistent_cache):
+    """The smoke-sized ``make_train_step`` with all four sketch ops on
+    compiled Pallas, compiled for one described chip.  The backend here is
+    the CPU, so the step is told the chip can compile Pallas."""
+    import numpy as np
+    from jax.sharding import AxisType, Mesh
+    from repro.core import fetchsgd as F
+    from repro.kernels import ops as kernel_ops
+    from repro.launch import shapes, steps
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    fs = F.FetchSGDConfig(rows=ROWS, cols=1 << 12, k=64, impl="pallas")
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(kernel_ops, "pallas_compile_supported", lambda: True)
+        bundle = steps.make_train_step(
+            configs.get_smoke("gpt2s-federated"),
+            shapes.ShapeSpec("t", "train", 32, 4), mesh, fs)
+        return bundle.fn.lower(*bundle.inputs).compile().as_text()
+
+
+def test_the_step_names_its_four_kernels(step_text):
+    from repro.obs import layers
+    kernels = [layers.instruction_name(line) for line in step_text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert kernels
+    assert {k.split(".")[0] for k in kernels} == set(layers.KERNELS)
+
+
+def test_every_instruction_of_the_step_has_a_layer(step_text):
+    from repro.obs import layers
+    names = layers.op_layers(step_text)
+    unplaced = [line.strip()[:120] for line in step_text.splitlines()
+                if layers.instruction_name(line) not in (None, *names)
+                and not re.search(r" (parameter|constant|tuple|"
+                                  r"get-tuple-element|bitcast)\(", line)]
+    assert unplaced == []
+    assert set(names.values()) >= set(layers.LAYERS) - {layers.MERGE}
