@@ -23,11 +23,22 @@ VPU's 8x128 vector lanes.  We restructure both directions around the MXU:
   one-hot and summed over sublanes, followed by a sort-free median of the
   rows (a min/max network: Mosaic has no sort).
 
-Value contractions run at ``HIGHEST`` precision: at the default one the
-MXU rounds f32 operands to bf16.  Hashes (murmur-finalizer over 64-bit ids
-carried as two uint32 words) are computed on the fly from ``iota`` — no
-index tables in HBM, matching ``repro.core.hashing`` bit-for-bit so
-sketches from the kernel and the jnp path are interchangeable.
+Value contractions keep float32's precision.  The MXU multiplies bf16;
+``HIGHEST`` splits each f32 operand into three bf16 pieces, ``a = a1 + a2
++ a3``, and sums the six bf16 passes a1b1, a1b2, a2b1, a1b3, a2b2, a3b1.
+In the encode one operand is the 0/1 one-hot, which bf16 holds exactly:
+its second and third pieces are zero, and three of those passes multiply
+zeros.  So the encode splits only the values (``split_bf16``, exact) and
+contracts each piece against the bf16 one-hot in one pass, accumulated in
+f32: ``HIGHEST``'s own non-zero products, in half its passes.
+``Precision.HIGH`` (a1b1, a1b2, a2b1) would not be this: it keeps two
+pieces of the values, about 16 of their 24 bits.  The estimate and the
+fused server kernels still contract at ``HIGHEST``.
+
+Hashes (murmur-finalizer over 64-bit ids carried as two uint32 words) are
+computed on the fly from ``iota`` — no index tables in HBM, matching
+``repro.core.hashing`` bit-for-bit so sketches from the kernel and the jnp
+path are interchangeable.
 
 Validated in ``interpret=True`` mode on CPU against ``ref.py``; the
 compiled kernels are compiled for a described v5e in
@@ -114,6 +125,27 @@ def median_rows(xs: list) -> jax.Array:
     return (xs[m - 1] + xs[m]) * 0.5
 
 
+def split_bf16(x: jax.Array) -> list:
+    """Three bfloat16 pieces of float32 ``x`` whose float32 sum is ``x``.
+
+    Each piece is the top 16 bits (sign, exponent, 7 mantissa bits) of
+    what the pieces before it leave.  Truncating, not rounding, means no
+    piece can round up past the largest bfloat16, so ``x`` near the float32
+    limit splits too; the three pieces hold the 24-bit significand's top,
+    middle and last 8 bits, so their sum is exact wherever they are normal
+    numbers.  A piece below 2**-126 flushes to zero, as it does in any
+    float32 arithmetic that flushes subnormals.
+    """
+    pieces = []
+    for _ in range(3):
+        top = jax.lax.bitcast_convert_type(
+            jax.lax.bitcast_convert_type(x, U32) & U32(0xFFFF0000),
+            jnp.float32)
+        pieces.append(top.astype(jnp.bfloat16))
+        x = x - top
+    return pieces
+
+
 def _encode_kernel(off_ref, values_ref, out_ref, *, rows: int, cols: int,
                    key: int):
     pid = pl.program_id(0)
@@ -129,12 +161,16 @@ def _encode_kernel(off_ref, values_ref, out_ref, *, rows: int, cols: int,
     c_outer = cols // LANES
     for j in range(rows):
         idx = hashing.bucket_hash(lo, hi, j, cols, key)
-        sv = hashing.sign_hash(lo, hi, j, key) * v
+        pieces = [p.astype(jnp.float32)
+                  for p in split_bf16(hashing.sign_hash(lo, hi, j, key) * v)]
         acc = None
         for r in range(BLOCK_ROWS):
             o_t, l_t = onehots_t(idx[r:r + 1, :], c_outer)
-            tile = scatter_tile(o_t, l_t * sv[r:r + 1, :], HIGHEST)
-            acc = tile if acc is None else acc + tile
+            o_t = o_t.astype(jnp.bfloat16)
+            for p in pieces:
+                tile = scatter_tile(o_t, (l_t * p[r:r + 1, :]).astype(
+                    jnp.bfloat16))
+                acc = tile if acc is None else acc + tile
         out_ref[j, :, :] += acc
 
 

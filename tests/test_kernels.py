@@ -63,21 +63,92 @@ def test_median_rows_matches_jnp_median(rng, rows):
     np.testing.assert_array_equal(got, jnp.median(jnp.asarray(x), axis=0))
 
 
-@pytest.mark.parametrize("offset", [0, 2**31 - 5, 2**32 - 3, 2**41 + 99])
-def test_encode_64bit_offsets(rng, offset):
-    """Hash identity must survive the 32-bit word boundary (d ~ 4e11)."""
+@pytest.mark.parametrize("traced", [False, True], ids=["static", "words"])
+@pytest.mark.parametrize("offset", [0, 2**31 - 5, 2**32 - 3, 2**41 + 99,
+                                    (3 << 32) + 12345])
+def test_encode_64bit_offsets(rng, offset, traced):
+    """Hash identity must survive the 32-bit word boundary (d ~ 4e11), for a
+    static offset and for the traced ``[lo, hi]`` words alike."""
     v = jnp.asarray(rng.normal(size=500).astype(np.float32))
-    out = pk.sketch_encode(v, offset, 3, 512, interpret=True)
+    if traced:
+        off = jnp.asarray([offset & 0xFFFFFFFF, offset >> 32], jnp.uint32)
+        out = pk.sketch_encode_words(v, off, 3, 512, interpret=True)
+    else:
+        out = pk.sketch_encode(v, offset, 3, 512, interpret=True)
     want = ref.sketch_encode(v, offset, 3, 512)
     np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-5)
 
 
-def test_encode_words_dynamic_offset(rng):
-    v = jnp.asarray(rng.normal(size=700).astype(np.float32))
-    off = jnp.asarray([12345, 3], jnp.uint32)   # = 3*2^32 + 12345
-    out = pk.sketch_encode_words(v, off, 3, 512, interpret=True)
-    want = ref.sketch_encode(v, (3 << 32) + 12345, 3, 512)
-    np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-5)
+F32_MAX = float(np.finfo(np.float32).max)
+F32_TINY = float(np.finfo(np.float32).tiny)           # 2**-126
+SPLIT_BF16 = pk.split_bf16
+
+
+def low_bit_values(rng, n, exponent, middle=False):
+    """``±2**exponent * (1 + m * 2**-23)``, m in [64, 256): float32 values
+    whose only set mantissa bits are the lowest eight.  With ``middle`` the
+    top bit of the middle eight (2**-8) is set too, so that each of the
+    three bf16 pieces holds some of the value."""
+    m = rng.integers(64, 256, size=n)
+    sign = rng.choice([-1.0, 1.0], size=n)
+    frac = 1.0 + (2.0**-8 if middle else 0.0) + m * 2.0**-23
+    return (sign * np.ldexp(frac, exponent)).astype(np.float32)
+
+
+def split_case(rng, case):
+    if case == "normals":
+        return (rng.normal(size=4096)
+                * np.exp(rng.normal(scale=8.0, size=4096))).astype(np.float32)
+    if case == "zeros":
+        return np.array([0.0, -0.0], np.float32)
+    if case == "limits":
+        # the largest finite value (rounded to bf16 it would be inf), the
+        # smallest normal, and the least exponent at which the lowest
+        # mantissa bit is still normal
+        return np.array([F32_MAX, -F32_MAX, F32_TINY, -F32_TINY,
+                         np.ldexp(1.0 + 255 * 2.0**-23, 127),
+                         np.ldexp(1.0 + 255 * 2.0**-23, -103)], np.float32)
+    return np.concatenate([low_bit_values(rng, 64, e, middle)
+                           for e in (-103, -60, 0, 60, 127)
+                           for middle in (False, True)])
+
+
+@pytest.mark.parametrize("case", ["normals", "zeros", "limits", "low_bits"])
+def test_split_bf16_is_exact(rng, case):
+    x = split_case(rng, case)
+    pieces = pk.split_bf16(jnp.asarray(x))
+    assert len(pieces) == 3
+    assert all(p.dtype == jnp.bfloat16 for p in pieces)
+    back = sum(p.astype(jnp.float32) for p in pieces)
+    np.testing.assert_array_equal(np.asarray(back), x)
+
+
+def encode_low_bits(rng, exponent, middle):
+    v = jnp.asarray(low_bit_values(rng, 1024, exponent, middle))
+    off = jnp.asarray([777, 0], jnp.uint32)
+    out = pk.sketch_encode_words(v, off, 3, 8192, key=5, interpret=True)
+    return out, ref.sketch_encode(v, 777, 3, 8192, key=5)
+
+
+@pytest.mark.parametrize("middle", [False, True], ids=["low8", "low8_mid"])
+@pytest.mark.parametrize("exponent", [-100, 0, 100])
+def test_encode_low_bits_matches_ref(rng, exponent, middle):
+    """The encode keeps every bit of a float32 value: the third bf16 piece
+    carries the lowest eight mantissa bits."""
+    out, want = encode_low_bits(rng, exponent, middle)
+    np.testing.assert_allclose(out, want, rtol=1e-6)
+
+
+@pytest.mark.parametrize("exponent", [-100, 0, 100])
+def test_two_piece_split_misses_low_bits(rng, monkeypatch, exponent):
+    """A planted fault: two pieces of the values (what ``Precision.HIGH``
+    keeps, about 16 bits) lose the lowest eight bits of values that use all
+    three pieces, and the check above fails.  (Values with no middle bits
+    need only two pieces: the second then takes the lowest eight.)"""
+    monkeypatch.setattr(pk, "split_bf16", lambda x: SPLIT_BF16(x)[:2])
+    out, want = encode_low_bits(rng, exponent, middle=True)
+    with pytest.raises(AssertionError):
+        np.testing.assert_allclose(out, want, rtol=1e-6)
 
 
 def test_zero_padding_is_noop(rng):

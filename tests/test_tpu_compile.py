@@ -76,6 +76,7 @@ def compile_tpu(one_chip, no_persistent_cache):
                 for s, d in shapes]
         text = jax.jit(fn).lower(*args).compile().as_text()
         assert "tpu_custom_call" in text
+        return text
     return go
 
 
@@ -86,8 +87,17 @@ def n(request, gpt2s_chunk_len):
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=str)
 def test_encode_compiles(compile_tpu, n, dtype):
-    compile_tpu(lambda v, off: pk.sketch_encode_words(v, off, ROWS, COLS),
-                ((n,), dtype), ((2,), jnp.uint32))
+    text = compile_tpu(
+        lambda v, off: pk.sketch_encode_words(v, off, ROWS, COLS),
+        ((n,), dtype), ((2,), jnp.uint32))
+    # the kernel still makes the f32 (rows, cols/128, 128) table from the
+    # offset words and a lane-dense (n, 128) view: the shapes by which the
+    # chip benchmark's encode_ms finds it in a trace
+    (line,) = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert re.search(rf"= f32\[{ROWS},{COLS // 128},128\]\S* custom-call\(",
+                     line)
+    assert re.search(r"operand_layout_constraints=\{u32\[2\]\S*, "
+                     r"(f32|bf16)\[\d+,128\]", line)
 
 
 def test_estimate_compiles(compile_tpu, n):
