@@ -1,12 +1,14 @@
 """Operation and byte counts, and the chip's peaks, kept with the benchmark.
 
-Counts come from the configuration and the traffic alone, never from the
-program, so that no change to the program can move them.
+Counts come from the configuration, its model reference (``models/``) and
+the traffic alone, never from the program, so that no change to the
+program can move them.
 """
 
 from __future__ import annotations
 
 import json
+import math
 from pathlib import Path
 
 HERE = Path(__file__).resolve().parent
@@ -21,43 +23,24 @@ def peaks(device_kind: str, root: Path = HERE) -> dict:
     return table[device_kind]
 
 
-def head_dim(cfg: dict) -> int:
-    return cfg.get("head_dim") or cfg["d_model"] // cfg["n_heads"]
+def param_count(model, cfg: dict) -> int:
+    """d: every weight of ``model``'s ``param_shapes``, which are the
+    weights one chip holds."""
+    return sum(math.prod(shape)
+               for shape, _ in model.param_shapes(cfg).values())
 
 
-def layer_params(cfg: dict) -> int:
-    """Weights of one decoder layer: attention, MLP and two norm scales."""
-    d, ff, hd = cfg["d_model"], cfg["d_ff"], head_dim(cfg)
-    attn = d * hd * (2 * cfg["n_heads"] + 2 * cfg["n_kv_heads"])
-    mlp = (3 if cfg["act"] == "swiglu" else 2) * d * ff
-    return attn + mlp + 2 * d
+def model_flops_per_token(model, cfg: dict, seq_len: int) -> int:
+    """Forward and backward operations per token, as ``model`` counts
+    them: over the weights a token multiplies."""
+    return model.flops_per_token(cfg, seq_len)
 
 
-def param_count(cfg: dict) -> int:
-    """d: every weight, the embedding and the untied output head included."""
-    d, V = cfg["d_model"], cfg["vocab"]
-    return cfg["n_layers"] * layer_params(cfg) + 2 * V * d + d
-
-
-def non_embedding_params(cfg: dict) -> int:
-    """N of 6·N·T: every weight but the input embedding (the head counts)."""
-    return param_count(cfg) - cfg["vocab"] * cfg["d_model"]
-
-
-def model_flops_per_token(cfg: dict, seq_len: int) -> int:
-    """Forward and backward: 6·N plus 12·L·S·(heads·head_dim) for the
-    attention scores and mixing, counted over the full S x S square as the
-    model computes it."""
-    attn_width = cfg["n_heads"] * head_dim(cfg)
-    return (6 * non_embedding_params(cfg)
-            + 12 * cfg["n_layers"] * seq_len * attn_width)
-
-
-def encode_least(cfg: dict, tr: dict, grad_bytes: int = 4) -> dict:
+def encode_least(model, cfg: dict, tr: dict, grad_bytes: int = 4) -> dict:
     """What any sketch encode of one client's gradient must do: read d
     gradient values, write the (rows, cols) f32 table, and make rows·d
     additions."""
-    d = param_count(cfg)
+    d = param_count(model, cfg)
     return {"bytes": d * grad_bytes + tr["rows"] * tr["cols"] * 4,
             "ops": tr["rows"] * d}
 

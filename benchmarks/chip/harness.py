@@ -2,9 +2,11 @@
 
 Everything a cell needs is found by name: the cell in ``BENCHMARK.json``
 gives its configuration (``configs/<config>.json``), its traffic
-(``traffic/<traffic>.json``) and its chips; the limits of its check are in
-``limits/<cell>.json``; each per-layer metric is read by
-``metrics/<metric>.py``.  Adding any of these is adding files.
+(``traffic/<traffic>.json``) and its chips; the configuration names its
+model reference (``models/<reference>.py``: weights' shapes, loss and
+gradient, counts); the limits of its check are in ``limits/<cell>.json``;
+each per-layer metric is read by ``metrics/<metric>.py``.  Adding any of
+these is adding files.
 
 The system under test is the FetchSGD mesh trainer:
 ``repro.launch.steps.make_train_step`` on a ``data = chips, model = 1``
@@ -42,18 +44,25 @@ def log(*parts) -> None:
 # -- finding a cell's files -----------------------------------------------------
 
 def load_cell(name: str, bench: dict, root: Path = HERE) -> dict:
-    """The cell's entry with its configuration, traffic and limits."""
+    """The cell's entry with its configuration, model reference, traffic
+    and limits."""
     cells = {c["name"]: c for c in bench["workloads"]}
     if name not in cells:
         raise KeyError(f"no workload {name!r} in BENCHMARK.json")
     cell = dict(cells[name])
-    cfg = json.loads((root / "configs" / f"{cell['config']}.json").read_text())
+    cfg_path = root / "configs" / f"{cell['config']}.json"
+    cfg = json.loads(cfg_path.read_text())
+    if "reference" not in cfg:
+        raise KeyError(f"{cfg_path} names no model reference: give it "
+                       f'"reference": "<name>" of models/<name>.py')
+    model = model_module(cfg["reference"], root)
     tr = traffic.load(cell["traffic"], root)
     if tr["clients"] != cell["chips"]:
         raise ValueError(f"{name}: traffic {cell['traffic']} has "
                          f"{tr['clients']} clients for {cell['chips']} chips")
     limits = json.loads((root / "limits" / f"{name}.json").read_text())
-    return {"cell": cell, "cfg": cfg, "tr": tr, "limits": limits}
+    return {"cell": cell, "cfg": cfg, "model": model, "tr": tr,
+            "limits": limits}
 
 
 def metric_entries(bench: dict, cell: str, group: str) -> list:
@@ -62,15 +71,29 @@ def metric_entries(bench: dict, cell: str, group: str) -> list:
             if "workloads" not in m or cell in m["workloads"]]
 
 
-def reader(name: str, root: Path = HERE):
-    """The ``read(ctx)`` function of ``metrics/<name>.py``."""
-    path = root / "metrics" / f"{name}.py"
+def _module(path: Path, name: str):
+    """The module of the file ``path``; its directory goes on the import
+    path, so that files beside it can import each other."""
     if str(path.parent) not in sys.path:
         sys.path.insert(0, str(path.parent))
-    spec = importlib.util.spec_from_file_location(f"metric_{name}", path)
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def reader(name: str, root: Path = HERE):
+    """The ``read(ctx)`` function of ``metrics/<name>.py``."""
+    return _module(root / "metrics" / f"{name}.py", f"metric_{name}").read
+
+
+def model_module(name: str, root: Path = HERE):
+    """The model reference ``models/<name>.py`` (its contract is in
+    ``models/decoder.py``)."""
+    path = root / "models" / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"no model reference {path}")
+    return _module(path, f"model_{name}")
 
 
 # -- devices --------------------------------------------------------------------
@@ -155,9 +178,11 @@ def host_change(parts: list) -> dict:
 
 
 class Program:
-    """The compiled train step on the cell's mesh, and its state."""
+    """The compiled train step on the cell's mesh, and its state; its
+    weights are ``model``'s, made from the seed."""
 
-    def __init__(self, cfg: dict, tr: dict, chips: int, fault: str | None):
+    def __init__(self, model, cfg: dict, tr: dict, chips: int,
+                 fault: str | None):
         import jax
         import jax.numpy as jnp
         from repro.core import fetchsgd as F
@@ -177,7 +202,7 @@ class Program:
                                        aggregate=tr["merge"])
         want = jax.tree.map(lambda s: (s.shape, s.dtype), bundle.inputs[0])
         mine = jax.tree.map(lambda s: (s.shape, s.dtype), jax.eval_shape(
-            lambda: reference.init_params(cfg["model"],
+            lambda: reference.init_params(model, cfg["model"],
                                           jax.random.PRNGKey(0))))
         if want != mine:
             raise ValueError("the benchmark's weights do not match the "
@@ -185,7 +210,7 @@ class Program:
         self.step = bundle.fn.lower(*bundle.inputs).compile()
         p_sh, o_sh, self.batch_sh, _ = self.step.input_shardings[0]
         self.init = jax.jit(
-            lambda key: reference.init_params(cfg["model"], key),
+            lambda key: reference.init_params(model, cfg["model"], key),
             out_shardings=p_sh)
         self.opt0 = jax.device_put(F.init_state(fs), o_sh)
         self.lr = jnp.float32(tr["lr"])
@@ -260,16 +285,17 @@ def window(prog: Program, pool: list, seconds: float) -> dict:
 
 # -- the check ------------------------------------------------------------------
 
-def reference_readings(cfg: dict, tr: dict, seed: int, batches: list,
-                       q=None, sketch_q=None, log=None) -> dict:
-    """The plain reference through the checked rounds, on one device;
-    ``q`` and ``sketch_q`` put a lower precision in (see
-    :func:`reference.fetchsgd_rounds`)."""
+def reference_readings(model, cfg: dict, tr: dict, seed: int,
+                       batches: list, q=None, sketch_q=None,
+                       log=None) -> dict:
+    """The plain reference through the checked rounds, on one device, with
+    ``model`` as the model; ``q`` and ``sketch_q`` put a lower precision
+    in (see :func:`reference.fetchsgd_rounds`)."""
     import jax
-    model = cfg["model"]
-    p0 = jax.jit(lambda k: reference.init_params(model, k))(
+    mcfg = cfg["model"]
+    p0 = jax.jit(lambda k: reference.init_params(model, mcfg, k))(
         reference.seed_key(seed))
-    out = reference.fetchsgd_rounds(p0, batches, model, tr, q=q,
+    out = reference.fetchsgd_rounds(p0, batches, model, mcfg, tr, q=q,
                                     sketch_q=sketch_q, log=log)
     change = jax.jit(functools.partial(
         sparse_change, n_max=tr["k"] * len(batches)))
@@ -356,7 +382,7 @@ def run(spec: dict, bench: dict, seed: int, seconds: float, trace: bool,
     The tests drive it off the chip (``require_chip=False``), without the
     compile cache, and with a ``fault`` planted under the timed path."""
     import jax
-    cell, cfg, tr = spec["cell"], spec["cfg"], spec["tr"]
+    cell, cfg, model, tr = (spec[k] for k in ("cell", "cfg", "model", "tr"))
     if seed < 0:
         raise ValueError("the seed is a whole number >= 0")
     devs = devices(cell["chips"], require_chip)
@@ -366,7 +392,7 @@ def run(spec: dict, bench: dict, seed: int, seconds: float, trace: bool,
     batches = traffic.rounds(tr, vocab, seed)
     check, pool = batches[:tr["check_rounds"]], batches[tr["check_rounds"]:]
 
-    prog = Program(cfg, tr, cell["chips"], fault)
+    prog = Program(model, cfg, tr, cell["chips"], fault)
     prog.start(seed)
     got = checked_rounds(prog, check)
     setup_s = time.perf_counter() - t_start
@@ -388,7 +414,7 @@ def run(spec: dict, bench: dict, seed: int, seconds: float, trace: bool,
     del prog
 
     t_ref = time.perf_counter()
-    ref = reference_readings(cfg, tr, seed, check, log=log)
+    ref = reference_readings(model, cfg, tr, seed, check, log=log)
     numbers = compare(got, ref)
     ok, compared = judge(numbers, spec["limits"])
     log(f"reference {time.perf_counter() - t_ref:.3f} s; losses "
@@ -415,8 +441,9 @@ def run(spec: dict, bench: dict, seed: int, seconds: float, trace: bool,
         busy = tracing.busy_seconds(ops)
         ctx = {"ops": ops, "spans": spans, "window_s": hi - lo,
                "busy_s": busy, "rounds": rounds, "chips": cell["chips"],
-               "round_s": win["seconds"] / rounds, "cfg": cfg["model"],
-               "tr": tr, "peak": counts.peaks(kind)}
+               "round_s": win["seconds"] / rounds, "config": cfg,
+               "cfg": cfg["model"], "model": model, "tr": tr,
+               "peak": counts.peaks(kind)}
         metrics = {}
         for m in metric_entries(bench, cell["name"], "per_layer"):
             value = reader(m["name"])(ctx)

@@ -25,23 +25,11 @@ op of it; an op the map gives no layer is ``unnamed``, never a guess.
 from __future__ import annotations
 
 import heapq
-import json
 import time
-from pathlib import Path
 
 import tracing
 
-HERE = Path(__file__).resolve().parent
 UNNAMED = "unnamed"
-
-
-def config_for(model: dict, root: Path = HERE) -> dict:
-    """The configuration in ``configs/`` whose ``model`` is ``model``."""
-    for path in sorted((root / "configs").glob("*.json")):
-        cfg = json.loads(path.read_text())
-        if cfg.get("model") == model:
-            return cfg
-    raise LookupError("no configuration in configs/ has this model")
 
 
 def module_text(compiled) -> str:
@@ -66,7 +54,7 @@ def op_layers(ctx: dict) -> dict | None:
             return None
         import harness
         t0 = time.perf_counter()
-        prog = harness.Program(config_for(ctx["cfg"]), ctx["tr"],
+        prog = harness.Program(ctx["model"], ctx["config"], ctx["tr"],
                                ctx["chips"], None)
         t1 = time.perf_counter()
         try:

@@ -2,9 +2,9 @@
 
 Layer: sketch encode.  Moves ``round_s``.  The least time any encode of
 one client's gradient could take (``counts.encode_least``: read d gradient
-values, write the f32 table, make rows·d additions, at the chip's peaks)
-over the encode's device time per round on a chip; nothing where
-``encode_ms`` finds no op.
+values, d from the configuration's model reference, write the f32 table,
+make rows·d additions, at the chip's peaks) over the encode's device time
+per round on a chip; nothing where ``encode_ms`` finds no op.
 """
 
 import counts
@@ -15,6 +15,6 @@ def read(ctx):
     ms = encode_ms.read(ctx)
     if ms is None:
         return None
-    least = counts.least_seconds(counts.encode_least(ctx["cfg"], ctx["tr"]),
-                                 ctx["peak"])
+    work = counts.encode_least(ctx["model"], ctx["cfg"], ctx["tr"])
+    least = counts.least_seconds(work, ctx["peak"])
     return 100.0 * least / (ms / 1e3)

@@ -1,10 +1,13 @@
 """Plain float32 reference of one FetchSGD round, independent of the program.
 
 Everything here follows the published descriptions, written out in
-straightforward ``jax.numpy`` at ``highest`` matmul precision:
+straightforward ``jax.numpy`` at ``highest`` matmul precision.  The model
+is the configuration's own: a module of ``models/`` that gives its weights'
+shapes, its loss and gradient, and its counts (the contract is in
+``models/decoder.py``).  This module holds what every model shares:
 
-* the decoder (RMSNorm, rotary attention with grouped KV heads, GELU or
-  SwiGLU MLP, untied output head), its mean next-token loss and gradient;
+* the seeded float32 weights from the model's shapes, and the quantizers
+  that the controls put into every model's matmuls;
 * the Count Sketch of the gradient over the flat parameter vector, whose
   element ids run through the leaves in sorted-key order, row-major; the
   hash family is murmur3's fmix32 over the two 32-bit words of the id;
@@ -15,8 +18,8 @@ straightforward ``jax.numpy`` at ``highest`` matmul precision:
 
 It imports nothing of the program and takes nothing the program made: the
 weights come from :func:`init_params` with the benchmark's seed.  Work runs
-in blocks (one sequence, one slice of ids at a time) so that it fits next to
-nothing else on one chip.
+in blocks (the model's, and one slice of ids at a time) so that it fits
+next to nothing else on one chip.
 """
 
 from __future__ import annotations
@@ -36,29 +39,6 @@ _ROW_SEEDS = (0x9E3779B9, 0x85EBCA6B, 0xC2B2AE35, 0x27D4EB2F, 0x165667B1,
 
 # -- weights ------------------------------------------------------------------
 
-def param_shapes(cfg: dict) -> dict:
-    """Leaf path -> (shape, init std; None for a norm scale of ones)."""
-    d, L, V, ff = cfg["d_model"], cfg["n_layers"], cfg["vocab"], cfg["d_ff"]
-    H, KV = cfg["n_heads"], cfg["n_kv_heads"]
-    hd = cfg.get("head_dim") or d // H
-    out = {
-        "embed/table": ((V, d), 0.02),
-        "final_norm/scale": ((d,), None),
-        "unembed/w": ((d, V), d ** -0.5),
-        "units/m0/attn/wq": ((L, d, H, hd), d ** -0.5),
-        "units/m0/attn/wk": ((L, d, KV, hd), d ** -0.5),
-        "units/m0/attn/wv": ((L, d, KV, hd), d ** -0.5),
-        "units/m0/attn/wo": ((L, H, hd, d), (H * hd) ** -0.5),
-        "units/m0/mlp/w_up": ((L, d, ff), d ** -0.5),
-        "units/m0/mlp/w_down": ((L, ff, d), ff ** -0.5),
-        "units/m0/norm1/scale": ((L, d), None),
-        "units/m0/norm2/scale": ((L, d), None),
-    }
-    if cfg["act"] == "swiglu":
-        out["units/m0/mlp/w_gate"] = ((L, d, ff), d ** -0.5)
-    return dict(sorted(out.items()))
-
-
 def _nest(flat: dict) -> dict:
     tree: dict = {}
     for path, v in flat.items():
@@ -76,10 +56,11 @@ def seed_key(seed: int):
     return jax.random.fold_in(key, (seed >> 32) & 0x7FFFFFFF)
 
 
-def init_params(cfg: dict, key) -> dict:
-    """Seeded float32 weights, as a nested dict of the leaves above."""
+def init_params(model, cfg: dict, key) -> dict:
+    """Seeded float32 weights of ``model``'s leaves, as a nested dict."""
     flat = {}
-    for i, (path, (shape, std)) in enumerate(param_shapes(cfg).items()):
+    for i, (path, (shape, std)) in enumerate(
+            model.param_shapes(cfg).items()):
         if std is None:
             flat[path] = jnp.ones(shape, jnp.float32)
         else:
@@ -93,81 +74,9 @@ def leaves(tree: dict) -> list:
     return jax.tree_util.tree_leaves(tree)
 
 
-# -- model --------------------------------------------------------------------
+# -- quantizers ---------------------------------------------------------------
 
-def _rmsnorm(x, scale, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * scale
-
-
-def _rope(x, theta):
-    """Rotary embedding, halves rotated (x: (S, H, hd))."""
-    S, _, hd = x.shape
-    half = hd // 2
-    freqs = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    ang = jnp.arange(S, dtype=jnp.float32)[:, None] * freqs
-    cos, sin = jnp.cos(ang)[:, None], jnp.sin(ang)[:, None]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
-
-
-def seq_loss(p: dict, tokens, labels, cfg: dict, q=None):
-    """Mean next-token cross entropy of one sequence (tokens: (S,)).
-
-    ``q`` rounds the operands of every matmul, forward and backward, so
-    that a lower precision can be put in (None: float32 as given)."""
-    mm = jnp.einsum if q is None else functools.partial(_rounded_einsum, q)
-
-    eps, theta = cfg["norm_eps"], cfg["rope_theta"]
-    H, KV = cfg["n_heads"], cfg["n_kv_heads"]
-    x = p["embed"]["table"][tokens]
-    S, d = x.shape
-    hd = cfg.get("head_dim") or d // H
-    mask = jnp.tril(jnp.ones((S, S), bool))
-    u = p["units"]["m0"]
-    for layer in range(cfg["n_layers"]):
-        a = jax.tree.map(lambda w: w[layer], u)
-        h = _rmsnorm(x, a["norm1"]["scale"], eps)
-        qh = _rope(mm("sd,dhe->she", h, a["attn"]["wq"]), theta)
-        k = _rope(mm("sd,dhe->she", h, a["attn"]["wk"]), theta)
-        v = mm("sd,dhe->she", h, a["attn"]["wv"])
-        k = jnp.repeat(k, H // KV, axis=1)
-        v = jnp.repeat(v, H // KV, axis=1)
-        s = mm("qhe,khe->hqk", qh, k) * hd ** -0.5
-        w = jax.nn.softmax(jnp.where(mask, s, -jnp.inf), axis=-1)
-        o = mm("hqk,khe->qhe", w, v)
-        x = x + mm("qhe,hed->qd", o, a["attn"]["wo"])
-        h = _rmsnorm(x, a["norm2"]["scale"], eps)
-        mlp = a["mlp"]
-        up = mm("sd,df->sf", h, mlp["w_up"])
-        if cfg["act"] == "swiglu":
-            g = jax.nn.silu(mm("sd,df->sf", h, mlp["w_gate"])) * up
-        else:
-            g = jax.nn.gelu(up, approximate=True)
-        x = x + mm("sf,fd->sd", g, mlp["w_down"])
-    h = _rmsnorm(x, p["final_norm"]["scale"], eps)
-    logits = mm("sd,dv->sv", h, p["unembed"]["w"])
-    gold = jnp.take_along_axis(logits, labels[:, None], -1)[:, 0]
-    return jnp.mean(jax.nn.logsumexp(logits, -1) - gold)
-
-
-def loss_and_grad(p: dict, tokens, labels, cfg: dict, q=None):
-    """Mean loss and gradient over a batch (B, S), one sequence at a time.
-
-    Every sequence has the same length, so the batch mean is the mean of
-    the sequences' means."""
-    step = jax.value_and_grad(seq_loss)
-
-    def body(acc, tl):
-        loss, g = step(p, tl[0], tl[1], cfg, q)
-        return jax.tree.map(jnp.add, acc, (loss, g)), None
-
-    zero = (jnp.zeros((), jnp.float32), jax.tree.map(jnp.zeros_like, p))
-    (loss, g), _ = jax.lax.scan(body, zero, (tokens, labels))
-    n = tokens.shape[0]
-    return loss / n, jax.tree.map(lambda x: x / n, g)
-
-
-def _rounded_einsum(q, spec, a, b):
+def rounded_einsum(q, spec, a, b):
     """``einsum`` whose operands are rounded by ``q``, and whose gradients
     are einsums of rounded operands too."""
     @jax.custom_vjp
@@ -348,15 +257,16 @@ def apply_update(params: list, ids: np.ndarray, vals) -> list:
     return out
 
 
-def fetchsgd_rounds(params: dict, batches: list, cfg: dict, tr: dict,
-                    q=None, sketch_q=None, log=None) -> dict:
+def fetchsgd_rounds(params: dict, batches: list, model, cfg: dict,
+                    tr: dict, q=None, sketch_q=None, log=None) -> dict:
     """Run the reference through ``len(batches)`` rounds from ``params``.
 
     Each batch is the whole cohort's ``(tokens, labels)``; its mean gradient
     is the merge of the clients' sketches, by the sketch's linearity.
-    ``q`` rounds the model's matmul operands (see :func:`seq_loss`);
-    ``sketch_q`` rounds the values that the sketch's encode and estimate
-    contract: the gradient, and the error sketch the estimate reads.
+    ``model.loss_and_grad`` gives it, with ``q`` rounding the model's
+    matmul operands; ``sketch_q`` rounds the values that the sketch's
+    encode and estimate contract: the gradient, and the error sketch the
+    estimate reads.
     Returns the losses, the momentum sketch after the first round and the
     weights after the last; ``log`` gets each phase's seconds."""
     rows, cols, k = tr["rows"], tr["cols"], tr["k"]
@@ -368,7 +278,7 @@ def fetchsgd_rounds(params: dict, batches: list, cfg: dict, tr: dict,
     cur = leaves(params)
     sizes = [w.size for w in cur]
     su = se = jnp.zeros((rows, cols), jnp.float32)
-    grad_fn = jax.jit(functools.partial(loss_and_grad, cfg=cfg, q=q))
+    grad_fn = jax.jit(functools.partial(model.loss_and_grad, cfg=cfg, q=q))
     losses, su_first = [], None
     clock = _Clock(log)
     with jax.default_matmul_precision("highest"):

@@ -70,16 +70,17 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     bench = json.loads((HERE.parents[1] / "BENCHMARK.json").read_text())
     spec = harness.load_cell(args.workload, bench)
-    cell, cfg, tr = spec["cell"], spec["cfg"], spec["tr"]
+    cell, cfg, model, tr = (spec[k] for k in ("cell", "cfg", "model", "tr"))
     harness.devices(cell["chips"], require_chip=True)
     harness.enable_cache()
     vocab = cfg["model"]["vocab"]
-    prog = harness.Program(cfg, tr, cell["chips"], None)
+    prog = harness.Program(model, cfg, tr, cell["chips"], None)
     refs: dict = {}
 
     def ref_for(seed, check):
         if seed not in refs:
-            refs[seed] = harness.reference_readings(cfg, tr, seed, check)
+            refs[seed] = harness.reference_readings(model, cfg, tr, seed,
+                                                    check)
         return refs[seed]
 
     def program(seed, fault=None):
@@ -112,16 +113,17 @@ def main(argv=None) -> int:
              {"sketch_q": reference.bf16})):
         for seed in seed_list:
             check = traffic.rounds(tr, vocab, seed)[:tr["check_rounds"]]
-            ctl = harness.reference_readings(cfg, tr, seed, check, **kw)
+            ctl = harness.reference_readings(model, cfg, tr, seed, check,
+                                             **kw)
             reading(kind, seed, ctl, ref_for(seed, check))
     for seed in args.bf16_reference_seeds:
         got, check = program(seed)
-        ref = harness.reference_readings(cfg, tr, seed, check,
+        ref = harness.reference_readings(model, cfg, tr, seed, check,
                                          q=reference.bf16)
         reading("program_vs_bf16_reference", seed, got, ref)
     if cell["chips"] > 1 and args.fault_seeds:
         prog.free()
-        prog = harness.Program(cfg, tr, cell["chips"], "no_exchange")
+        prog = harness.Program(model, cfg, tr, cell["chips"], "no_exchange")
         for seed in args.fault_seeds:
             got, check = program(seed)
             reading("no_exchange", seed, got, ref_for(seed, check))

@@ -10,21 +10,21 @@ import pytest
 
 import counts
 import harness
-import reference
 
 HERE = Path(__file__).resolve().parents[1]
 BENCH = json.loads((HERE.parents[1] / "BENCHMARK.json").read_text())
 CONFIGS = {c["name"]: json.loads((HERE.parents[1] / c["file"]).read_text())
            for c in BENCH["configs"]}
 GPT2S, INTERN = CONFIGS["gpt2s-federated"], CONFIGS["internlm2-1.8b-l4"]
+DECODER = harness.model_module("decoder")
 
 
 @pytest.mark.parametrize("cfg,d", [(GPT2S, 162_148_608),
                                    (INTERN, 630_736_896)])
 def test_param_count_matches_hand_count(cfg, d):
     m = cfg["model"]
-    assert counts.param_count(m) == d
-    shapes = reference.param_shapes(m).values()
+    assert counts.param_count(DECODER, m) == d
+    shapes = DECODER.param_shapes(m).values()
     assert sum(math.prod(s) for s, _ in shapes) == d
 
 
@@ -33,9 +33,9 @@ def test_gpt2s_model_flops_by_hand():
     # 12 layers of 4·768² attention + 2·768·3072 MLP + 2·768 norms, the
     # head 768·50257 and the final norm 768: N = 123,551,232
     n = 12 * (4 * 768 * 768 + 2 * 768 * 3072 + 2 * 768) + 768 * 50257 + 768
-    assert counts.non_embedding_params(m) == n == 123_551_232
+    assert DECODER.non_embedding_params(m) == n == 123_551_232
     per_token = 6 * n + 12 * 12 * 1024 * 768
-    assert counts.model_flops_per_token(m, 1024) == per_token
+    assert counts.model_flops_per_token(DECODER, m, 1024) == per_token
     # one client of 8 x 1024 tokens: about 7.0e12 per round
     assert per_token * 8192 == pytest.approx(7.0e12, rel=0.01)
 
@@ -47,8 +47,9 @@ def test_internlm2_model_flops_by_hand():
     layer = 48 * 128 * 2048 + 3 * 2048 * 8192 + 2 * 2048
     assert layer == 62_918_656
     n = 4 * layer + 2048 * 92544 + 2048
-    assert counts.non_embedding_params(m) == n
-    assert counts.model_flops_per_token(m, 1024) * 8192 == pytest.approx(
+    assert DECODER.non_embedding_params(m) == n
+    per_token = counts.model_flops_per_token(DECODER, m, 1024)
+    assert per_token * 8192 == pytest.approx(
         (6 * n + 12 * 4 * 1024 * 2048) * 8192)
 
 
@@ -56,7 +57,7 @@ def test_internlm2_model_flops_by_hand():
                                    (INTERN, 630_736_896)])
 def test_encode_least_work(cfg, d):
     tr = {"rows": 5, "cols": 16384}
-    work = counts.encode_least(cfg["model"], tr)
+    work = counts.encode_least(DECODER, cfg["model"], tr)
     assert work == {"bytes": 4 * d + 5 * 16384 * 4, "ops": 5 * d}
     peak = counts.peaks("TPU v5 lite")
     assert counts.least_seconds(work, peak) == pytest.approx(

@@ -161,13 +161,12 @@ def test_module_text_falls_back_to_the_executables_modules():
     assert layer_map.module_text(Compiled()) == "HloModule m\nHloModule m"
 
 
-def test_the_map_comes_from_the_cells_compiled_step(monkeypatch):
+def test_the_map_comes_from_the_cells_compiled_step():
     """Off the chip, at a size a CPU test holds: the map of the harness's
     own program places the model, the sketch and the server."""
     spec = tiny_spec()
-    monkeypatch.setattr(layer_map, "config_for",
-                        lambda model: dict(spec["cfg"], model=model))
-    c = {"cfg": spec["cfg"]["model"], "tr": spec["tr"], "chips": 1}
+    c = {"config": spec["cfg"], "cfg": spec["cfg"]["model"],
+         "model": spec["model"], "tr": spec["tr"], "chips": 1}
     names = layer_map.op_layers(c)
     assert c["op_layers"] is names
     assert set(names.values()) >= {"forward", "backward", "sketch_encode",

@@ -30,6 +30,7 @@ TINY_TRAFFIC = dict(clients=1, seqs_per_client=4, seq_len=32, n_classes=10,
                     follow_prob=0.8, pool_rounds=2, check_rounds=3, rows=5,
                     cols=1024, k=32, lr=0.1, momentum=0.9, error_mode="zero",
                     momentum_masking=True, merge="flat", sketch_impl="auto")
+DECODER = harness.model_module("decoder")
 SEED = 2 ** 31 + 7
 FAULTS = ("unchanged", "half_batch", "sign_flip", "moved_ids")
 
@@ -37,8 +38,9 @@ FAULTS = ("unchanged", "half_batch", "sign_flip", "moved_ids")
 def tiny_spec(chips=1):
     tr = dict(TINY_TRAFFIC, clients=chips)
     return {"cell": {"name": "gpt2s.silo1.c14", "chips": chips},
-            "cfg": {"program_arch": "internlm2-1.8b", "model": TINY_MODEL},
-            "tr": tr, "limits": LIMITS}
+            "cfg": {"program_arch": "internlm2-1.8b", "reference": "decoder",
+                    "model": TINY_MODEL},
+            "model": DECODER, "tr": tr, "limits": LIMITS}
 
 
 def test_command_off_the_chip_exits_nonzero_and_prints_no_result():
@@ -53,11 +55,13 @@ def test_command_off_the_chip_exits_nonzero_and_prints_no_result():
 
 
 def test_a_new_cell_and_metric_are_found_by_name(tmp_path):
-    for sub in ("configs", "traffic", "limits", "metrics"):
+    for sub in ("configs", "traffic", "limits", "metrics", "models"):
         (tmp_path / sub).mkdir()
     (tmp_path / "configs" / "tiny.json").write_text(json.dumps(
         {"name": "tiny", "program_arch": "gpt2s-federated",
-         "model": TINY_MODEL}))
+         "reference": "tiny_lm", "model": TINY_MODEL}))
+    (tmp_path / "models" / "tiny_lm.py").write_text(
+        "def param_shapes(cfg):\n    return {}\n")
     (tmp_path / "traffic" / "silo1.tiny.json").write_text(
         json.dumps(TINY_TRAFFIC))
     (tmp_path / "limits" / "tiny.silo1.json").write_text(
@@ -70,6 +74,7 @@ def test_a_new_cell_and_metric_are_found_by_name(tmp_path):
                             "workloads": ["tiny.silo1"]}]}
     spec = harness.load_cell("tiny.silo1", bench, root=tmp_path)
     assert spec["tr"]["cols"] == 1024 and spec["cfg"]["name"] == "tiny"
+    assert spec["model"].param_shapes(TINY_MODEL) == {}
     assert [m["name"] for m in harness.metric_entries(
         bench, "tiny.silo1", "per_layer")] == ["rounds_seen"]
     assert harness.reader("rounds_seen", root=tmp_path)({"rounds": 3}) == 3.0
@@ -111,9 +116,10 @@ def test_a_planted_fault_is_not_correct(fault):
 def test_the_control_is_not_correct():
     spec = tiny_spec()
     check = traffic.rounds(spec["tr"], 256, SEED)[:3]
-    ref = harness.reference_readings(spec["cfg"], spec["tr"], SEED, check)
-    ctl = harness.reference_readings(spec["cfg"], spec["tr"], SEED, check,
-                                     q=reference.int8)
+    ref = harness.reference_readings(DECODER, spec["cfg"], spec["tr"], SEED,
+                                     check)
+    ctl = harness.reference_readings(DECODER, spec["cfg"], spec["tr"], SEED,
+                                     check, q=reference.int8)
     ok, compared = harness.judge(harness.compare(ctl, ref), LIMITS)
     assert not ok, compared
 
@@ -125,10 +131,12 @@ def test_the_sketch_control_hides_under_the_model_rounding():
     gap that PERF.md names."""
     spec = tiny_spec()
     check = traffic.rounds(spec["tr"], 256, SEED)[:3]
-    ref = harness.reference_readings(spec["cfg"], spec["tr"], SEED, check)
+    ref = harness.reference_readings(DECODER, spec["cfg"], spec["tr"], SEED,
+                                     check)
     ctl = harness.compare(harness.reference_readings(
-        spec["cfg"], spec["tr"], SEED, check, sketch_q=reference.bf16), ref)
-    prog = harness.Program(spec["cfg"], spec["tr"], 1, None)
+        DECODER, spec["cfg"], spec["tr"], SEED, check,
+        sketch_q=reference.bf16), ref)
+    prog = harness.Program(DECODER, spec["cfg"], spec["tr"], 1, None)
     prog.start(SEED)
     sound = harness.compare(harness.checked_rounds(prog, check), ref)
     assert 1e-4 < ctl["sketch_diff"] < sound["sketch_diff"]

@@ -58,6 +58,7 @@ SPANS = [("round.put_batch", 0.0, 1 * MS), ("round.dispatch", 1 * MS, 2 * MS),
 PEAK = {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}
 CFG = {"n_layers": 1, "d_model": 8, "n_heads": 2, "n_kv_heads": 2,
        "d_ff": 16, "vocab": 10, "act": "gelu"}
+DECODER = harness.model_module("decoder")
 
 
 def ctx(ops=OPS):
@@ -65,7 +66,8 @@ def ctx(ops=OPS):
     ops = tracing.clip(ops, lo, hi)
     return {"ops": ops, "spans": SPANS, "window_s": hi - lo,
             "busy_s": tracing.busy_seconds(ops), "rounds": 2, "chips": 2,
-            "round_s": 0.010, "peak": PEAK, "cfg": CFG, "tr": TR}
+            "round_s": 0.010, "peak": PEAK, "cfg": CFG, "model": DECODER,
+            "tr": TR}
 
 
 def per_round(ms):
@@ -130,13 +132,13 @@ def test_patterns_follow_the_traffic():
 
 def test_encode_roofline_is_least_time_over_encode_time():
     c = ctx()
-    least = counts.least_seconds(counts.encode_least(CFG, TR), PEAK)
+    least = counts.least_seconds(counts.encode_least(DECODER, CFG, TR), PEAK)
     assert harness.reader("encode_roofline")(c) == pytest.approx(
         100 * least / (9 / 2 / 2 * MS))
 
 
 def test_mfu_counts_model_flops_over_round_time():
-    flops = counts.model_flops_per_token(CFG, 4) * 2 * 1 * 4
+    flops = counts.model_flops_per_token(DECODER, CFG, 4) * 2 * 1 * 4
     assert harness.reader("mfu")(ctx()) == pytest.approx(
         100 * flops / (0.010 * 2 * 197e12))
 
